@@ -1,5 +1,6 @@
 """Benchmark harness: one module per paper table/figure.
-Prints ``name,us_per_call,derived`` CSV.
+Prints ``name,us_per_call,derived`` CSV; exits non-zero if any bench
+raised (its row reads ``ERROR``).
 
 ``--trace-out PATH`` enables span tracing for the whole harness and dumps
 one Chrome ``trace_event`` JSON artifact (load in chrome://tracing or
@@ -12,7 +13,7 @@ import sys
 import time
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
     trace_out = None
     if "--trace-out" in argv:
@@ -32,6 +33,7 @@ def main(argv=None) -> None:
         ("chaos", bench_chaos),
     ]
     tracer = telemetry.get_tracer()
+    errors = 0
     if trace_out:
         tracer.clear()
         tracer.start()
@@ -43,6 +45,7 @@ def main(argv=None) -> None:
                 for line in mod.main():
                     print(line, flush=True)
             except Exception as e:  # keep the harness running
+                errors += 1
                 print(f"{name},ERROR,{type(e).__name__}:{e}", flush=True)
             print(f"# {name} done in {time.perf_counter() - t0:.1f}s",
                   file=sys.stderr)
@@ -52,7 +55,8 @@ def main(argv=None) -> None:
             tracer.write_chrome(trace_out)
             print(f"# wrote {len(tracer.events())} spans to {trace_out}",
                   file=sys.stderr)
+    return 1 if errors else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -1,6 +1,6 @@
 """Architecture registry: --arch <id> resolves here."""
 
-from typing import Dict
+from typing import Dict, Optional
 
 from .base import (SHAPES, LONG_CONTEXT_ARCHS, HybridConfig, MLAConfig,
                    ModelConfig, MoEConfig, ShapeConfig, SSMConfig,
@@ -28,6 +28,23 @@ def get_arch(name: str) -> ModelConfig:
     return ARCHS[name]
 
 
+def job_config(name: str, *, smoke: bool,
+               num_layers: Optional[int] = None) -> ModelConfig:
+    """The config a train/serve job runs: the reduced CPU config under
+    ``smoke``, else the published one, optionally cut in depth only."""
+    cfg = get_arch(name)
+    if smoke:
+        if num_layers is not None:
+            raise ValueError("num_layers cuts a published config; the smoke "
+                             "config sets its own depth")
+        return reduce_for_smoke(cfg)
+    if num_layers is not None:
+        if num_layers < 1:
+            raise ValueError(f"num_layers must be positive, got {num_layers}")
+        cfg = cfg.with_(num_layers=num_layers)
+    return cfg
+
+
 def cell_is_runnable(arch: str, shape: str) -> bool:
     """long_500k only for sub-quadratic archs (DESIGN.md §4)."""
     if shape == "long_500k":
@@ -37,4 +54,5 @@ def cell_is_runnable(arch: str, shape: str) -> bool:
 
 __all__ = ["ARCHS", "SHAPES", "LONG_CONTEXT_ARCHS", "ModelConfig",
            "MoEConfig", "MLAConfig", "SSMConfig", "HybridConfig",
-           "ShapeConfig", "get_arch", "cell_is_runnable", "reduce_for_smoke"]
+           "ShapeConfig", "get_arch", "job_config", "cell_is_runnable",
+           "reduce_for_smoke"]

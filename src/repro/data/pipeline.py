@@ -74,8 +74,12 @@ class DeviceFeeder:
         self.prefetch = max(1, prefetch)
 
     def _put(self, batch: Dict[str, np.ndarray]) -> Dict[str, jax.Array]:
-        return {k: jax.device_put(v, self.shardings[k]) if k in self.shardings
-                else jax.device_put(v) for k, v in batch.items()}
+        missing = sorted(set(batch) - set(self.shardings))
+        if missing:
+            raise KeyError(f"batch keys {missing} have no sharding; a key "
+                           f"without one would land on a single device")
+        return {k: jax.device_put(v, self.shardings[k])
+                for k, v in batch.items()}
 
     def __iter__(self) -> Iterator[Dict[str, jax.Array]]:
         q: "queue.Queue" = queue.Queue(maxsize=self.prefetch)

@@ -18,16 +18,24 @@ from repro.core.dataset import Dataset
 def build_token_dataset(ds: Dataset, *, num_docs: int = 256,
                         doc_len: int = 1024, vocab_size: int = 50_000,
                         seed: int = 0, commit: bool = True) -> Dataset:
-    """Documents of int32 tokens (ragged lengths ±25%) + doc ids."""
+    """Documents of int32 tokens (ragged lengths ±25%) + doc ids.
+
+    Token ids follow a Zipf law (p_k ∝ 1/(k+1)), as word and code-token
+    frequencies do, so a model has a unigram distribution to learn and a
+    short training run shows its loss falling; uniform ids leave nothing
+    to learn."""
     if "tokens" not in ds.tensor_names:
         ds.create_tensor("tokens", htype="tokens", dtype="int32",
                          sample_compression="zlib",
                          min_chunk_size=256 << 10, max_chunk_size=1 << 20)
         ds.create_tensor("doc_id", htype="class_label")
     rng = np.random.default_rng(seed)
+    cdf = np.cumsum(1.0 / np.arange(1, vocab_size + 1))
+    cdf /= cdf[-1]
     for i in range(num_docs):
         n = int(doc_len * rng.uniform(0.75, 1.25))
-        ds.append({"tokens": rng.integers(0, vocab_size, n).astype(np.int32),
+        ids = np.searchsorted(cdf, rng.random(n), side="right")  # < cdf[-1]
+        ds.append({"tokens": ids.astype(np.int32),
                    "doc_id": np.int64(i)})
     if commit:
         ds.commit(f"synthetic tokens x{num_docs}")

@@ -14,8 +14,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def quantized_psum(x: jax.Array, axis_name: str) -> jax.Array:
@@ -35,11 +34,10 @@ def make_quantized_allreduce(mesh: Mesh, axis_name: str = "pod"):
     """Tree-level quantized mean-all-reduce over ``axis_name``."""
 
     def one(x):
-        rest = P(*([None] * x.ndim))
-        f = shard_map(functools.partial(quantized_psum, axis_name=axis_name),
-                      mesh=mesh, in_specs=P(axis_name, *([None] * (x.ndim - 1))),
-                      out_specs=P(None, *([None] * (x.ndim - 1))),
-                      check_rep=False)
+        f = jax.shard_map(
+            functools.partial(quantized_psum, axis_name=axis_name),
+            mesh=mesh, in_specs=P(axis_name, *([None] * (x.ndim - 1))),
+            out_specs=P(None, *([None] * (x.ndim - 1))), check_vma=False)
         return f(x)
 
     def allreduce(tree: Any) -> Any:

@@ -1,11 +1,16 @@
 """Flash attention forward kernel (TPU Pallas).
 
 Tiling: grid (B, H, nQ, nK), K-blocks innermost so each core streams KV
-blocks through VMEM while the (block_q, D) accumulator + (block_q,) softmax
+blocks through VMEM while the (block_q, D) accumulator + (block_q, 1) softmax
 stats live in VMEM scratch across the nK steps.  GQA is handled in the
 BlockSpec index maps (kv head = h // group_size), so no KV replication ever
 touches HBM.  Causal/sliding-window blocks that are fully masked are skipped
 with ``pl.when`` (the roofline win vs the masked XLA path).
+
+Layout: (B, S, H, D) is viewed as (B, S, H*D) (a free reshape), and a block
+is one head's (block, D) lane slice.  The TPU needs the last two block dims
+divisible by (8, 128) or equal to the array's, so on the chip D is a
+multiple of 128.
 
 Block sizes default to (128, 512): MXU-aligned (multiples of 128 on the
 contracted and lane dims) and sized so  q(128xD) + k,v(512xD) + acc fit in
@@ -50,9 +55,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(need_block())
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)                 # (bq, D)
+        k = k_ref[...].astype(jnp.float32)                 # (bk, D)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
@@ -63,19 +68,19 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         if window:
             ok = jnp.logical_and(ok, q_pos - k_pos < window)
         s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                                # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(ki == n_k - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
@@ -95,21 +100,21 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
     kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
                                window=window, block_q=block_q,
                                block_k=block_k, n_k=n_k)
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((None, block_q, D), lambda b, h, qi, ki: (b, qi, h))
+    kv_spec = pl.BlockSpec((None, block_k, D),
+                           lambda b, h, qi, ki: (b, ki, h // G))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),   # acc
-            pltpu.VMEM((block_q,), jnp.float32),     # m (running max)
-            pltpu.VMEM((block_q,), jnp.float32),     # l (running sum)
+            pltpu.VMEM((block_q, 1), jnp.float32),   # m (running max)
+            pltpu.VMEM((block_q, 1), jnp.float32),   # l (running sum)
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(q.reshape(B, S, H * D), k.reshape(B, T, Hkv * D),
+      v.reshape(B, T, Hkv * D))
+    return out.reshape(B, S, H, D)

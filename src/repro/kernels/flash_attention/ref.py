@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -24,7 +25,9 @@ def ref_attention(q, k, v, *, causal: bool = True, window: int = 0,
     if window:
         ok = ok & (qi - ki < window)
     s = jnp.where(ok[None, None, None], s, -1e30)
-    p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+    # the max cancels in p; keeping it out of the VJP (which the flash
+    # kernel's backward takes through this function) avoids a 0/0
+    p = jnp.exp(s - jax.lax.stop_gradient(s.max(axis=-1, keepdims=True)))
     p = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
     out = jnp.einsum("bgnst,btgd->bsgnd", p, v.astype(jnp.float32))
     return out.reshape(B, S, H, D).astype(q.dtype)

@@ -10,8 +10,13 @@ walks the chunks left-to-right, carrying the (N, P) state in scratch — the
 HBM traffic is exactly one read of x/dt/B/C and one write of y (+ one final
 state write), vs the XLA path's materialized (nc, N, P) inter-chunk states.
 
-Cumulative sums inside the kernel use a lower-triangular ones matmul
+Cumulative sums inside the kernel use a triangular ones matmul
 (MXU-friendly; avoids relying on mosaic scan lowering).
+
+Layout: the wrapper puts heads before the sequence, x as (B, nh, S, P) and
+B/C as (B, G, S, N), so every block's last two dims are a (Q, P) or (Q, N)
+tile.  dt comes in both as rows (B, nh, 1, S) and as columns (B, nh, S, 1),
+so the kernel needs no in-register transposes; A is read from SMEM.
 """
 
 from __future__ import annotations
@@ -24,36 +29,40 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
-                state_acc, *, chunk: int, n_chunks: int):
+def _ssd_kernel(a_ref, x_ref, dtr_ref, dtc_ref, b_ref, c_ref, y_ref,
+                state_ref, state_acc, *, chunk: int, n_chunks: int):
+    h = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         state_acc[...] = jnp.zeros_like(state_acc)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)            # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)             # (Q,)
-    A = a_ref[0, 0]                                      # scalar (negative)
-    Bm = b_ref[0, :, 0, :].astype(jnp.float32)           # (Q, N)
-    Cm = c_ref[0, :, 0, :].astype(jnp.float32)           # (Q, N)
+    x = x_ref[...].astype(jnp.float32)                   # (Q, P)
+    dt_row = dtr_ref[...]                                # (1, Q)
+    dt_col = dtc_ref[...]                                # (Q, 1)
+    A = a_ref[0, h]                                      # scalar (negative)
+    Bm = b_ref[...].astype(jnp.float32)                  # (Q, N)
+    Cm = c_ref[...].astype(jnp.float32)                  # (Q, N)
 
-    a = dt * A                                           # (Q,) log-decays
-    # inclusive cumsum via lower-triangular ones matmul (MXU)
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    tril_incl = (ii >= jj).astype(jnp.float32)           # i >= j
-    a_cum = jax.lax.dot_general(tril_incl, a[:, None],
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)[:, 0]
-    a_tot = a_cum[-1]
+    # inclusive cumsums of the log-decays, as a column and as a row
+    a_cum_col = jax.lax.dot_general((ii >= jj).astype(jnp.float32),
+                                    dt_col * A, (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+    a_cum_row = jax.lax.dot_general(dt_row * A,
+                                    (ii <= jj).astype(jnp.float32),
+                                    (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+    a_tot = jnp.sum(dt_row * A, axis=1, keepdims=True)   # (1, 1)
 
     # intra-chunk: masked-decay attention-like matmuls
-    seg = a_cum[:, None] - a_cum[None, :]                # sum over (j, i]
+    seg = a_cum_col - a_cum_row                          # sum over (j, i]
     L = jnp.where(ii >= jj, jnp.exp(seg), 0.0)
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    M = scores * L * dt[None, :]
+    M = scores * L * dt_row
     y_intra = jax.lax.dot_general(M, x, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
 
@@ -61,17 +70,17 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
     state = state_acc[...]                               # (N, P)
     y_inter = jax.lax.dot_general(Cm, state, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    y_inter = y_inter * jnp.exp(a_cum)[:, None]
-    y_ref[0, :, 0, :] = (y_intra + y_inter).astype(y_ref.dtype)
+    y_inter = y_inter * jnp.exp(a_cum_col)
+    y_ref[...] = (y_intra + y_inter).astype(y_ref.dtype)
 
-    wts = dt * jnp.exp(a_tot - a_cum)                    # (Q,)
-    upd = jax.lax.dot_general(Bm, x * wts[:, None], (((0,), (0,)), ((), ())),
+    wts = dt_col * jnp.exp(a_tot - a_cum_col)            # (Q, 1)
+    upd = jax.lax.dot_general(Bm, x * wts, (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
     state_acc[...] = state * jnp.exp(a_tot) + upd
 
     @pl.when(ci == n_chunks - 1)
     def _emit_state():
-        state_ref[0, 0, :, :] = state_acc[...]
+        state_ref[...] = state_acc[...]
 
 
 def ssd_fwd(x, dt, A, Bm, Cm, *, chunk: int = 256, interpret: bool = False):
@@ -83,28 +92,31 @@ def ssd_fwd(x, dt, A, Bm, Cm, *, chunk: int = 256, interpret: bool = False):
     Q = min(chunk, S)
     assert S % Q == 0, (S, Q)
     nc = S // Q
-    a2 = A.reshape(nh, 1).astype(jnp.float32)
+    dt_hs = jnp.moveaxis(dt.astype(jnp.float32), 2, 1)  # (B, nh, S)
 
     kernel = functools.partial(_ssd_kernel, chunk=Q, n_chunks=nc)
     y, state = pl.pallas_call(
         kernel,
         grid=(B, nh, nc),
         in_specs=[
-            pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, Q, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1, 1), lambda b, h, c: (h, 0)),
-            pl.BlockSpec((1, Q, 1, N), lambda b, h, c: (b, c, h // hg, 0)),
-            pl.BlockSpec((1, Q, 1, N), lambda b, h, c: (b, c, h // hg, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, None, Q, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((None, None, 1, Q), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec((None, None, Q, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((None, None, Q, N), lambda b, h, c: (b, h // hg, c, 0)),
+            pl.BlockSpec((None, None, Q, N), lambda b, h, c: (b, h // hg, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, 1, N, P), lambda b, h, c: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, Q, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((None, None, N, P), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct((B, nh, S, P), x.dtype),
             jax.ShapeDtypeStruct((B, nh, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
-    )(x, dt.astype(jnp.float32), a2, Bm, Cm)
-    return y, state
+    )(A.reshape(1, nh).astype(jnp.float32), jnp.moveaxis(x, 2, 1),
+      dt_hs[:, :, None, :], dt_hs[..., None], jnp.moveaxis(Bm, 2, 1),
+      jnp.moveaxis(Cm, 2, 1))
+    return jnp.moveaxis(y, 1, 2), state

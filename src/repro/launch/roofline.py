@@ -21,7 +21,11 @@ import re
 from dataclasses import asdict, dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-from .mesh import HW
+from .mesh import peaks
+
+# The chip the dry-run cells are costed for (a key of mesh.PEAKS).
+TARGET_KIND = "TPU v5 lite"
+_PEAKS = peaks(TARGET_KIND)
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -89,17 +93,17 @@ class Roofline:
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_device / HW["peak_flops_bf16"]
+        return self.flops_per_device / _PEAKS["peak_flops_bf16"]
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_device / HW["hbm_bw"]
+        return self.bytes_per_device / _PEAKS["hbm_bw"]
 
     @property
     def collective_s(self) -> float:
         # v5e: 4 ICI links/chip usable concurrently for ring collectives;
         # ring AR moves ~2x payload.  Conservative: 2 links effective.
-        eff_bw = 2 * HW["ici_bw"]
+        eff_bw = 2 * _PEAKS["ici_bw"]
         return 2.0 * self.collective_bytes / eff_bw
 
     @property
@@ -121,11 +125,13 @@ class Roofline:
     def roofline_fraction(self) -> float:
         """useful-compute time / achievable step time (higher = closer to
         the compute roofline)."""
-        useful_s = (self.model_flops_total / self.chips) / HW["peak_flops_bf16"]
+        useful_s = ((self.model_flops_total / self.chips)
+                    / _PEAKS["peak_flops_bf16"])
         return useful_s / self.bound_s if self.bound_s else 0.0
 
     def to_json(self) -> dict:
         d = asdict(self)
+        d["device_kind"] = TARGET_KIND     # the chip the terms are costed for
         for k in ("compute_s", "memory_s", "collective_s", "dominant",
                   "useful_flops_ratio", "roofline_fraction"):
             d[k] = getattr(self, k)
@@ -135,8 +141,6 @@ class Roofline:
 def extract_cost(compiled) -> Tuple[float, float, float]:
     """(flops, bytes_accessed, peak_memory) from a compiled executable."""
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     nbytes = float(ca.get("bytes accessed", 0.0))
     try:

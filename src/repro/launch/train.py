@@ -1,32 +1,36 @@
 """Training driver: Deep Lake streaming -> pjit train loop, with
 checkpoint/restart, straggler detection, failure injection and elastic
-restore.  Runs the production code path at any scale — examples use reduced
-configs on the local CPU mesh; the same Trainer drives pod-scale runs.
+restore.  The mesh spans the local devices: the chips of a TPU host, or the
+host CPU under tests.  ``smoke`` runs the reduced config; otherwise the
+published widths run, optionally cut in depth with ``num_layers``.
 
 CLI:
     python -m repro.launch.train --arch gemma-2b --smoke --steps 20
-    python -m repro.launch.train --arch starcoder2-3b --smoke --steps 50 \
-        --grad-compress --fail-at 12 --checkpoint-every 5
+    python -m repro.launch.train --arch starcoder2-3b --full --num-layers 4 \
+        --global-batch 2 --seq-len 2048 --steps 8
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.sharding import Mesh
 
 from repro.checkpoint import CheckpointManager
-from repro.configs import ARCHS, get_arch, reduce_for_smoke
+from repro.configs import ARCHS, job_config
 from repro.core.dataset import Dataset
 from repro.core.storage import MemoryProvider, SimulatedS3Provider, chain
 from repro.core.views import DatasetView
 from repro.data import DeviceFeeder, TokenBatcher, build_token_dataset
 from repro.distributed import (FailureInjector, StragglerDetector, make_rules,
                                make_shard_fn, sharding_for_specs)
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import init_state, make_train_step, train_state_specs
 from repro.models.model import build_model
@@ -37,6 +41,7 @@ from repro.optim import AdamW, cosine_schedule
 class TrainJob:
     arch: str = "gemma-2b"
     smoke: bool = True              # reduced config (CPU scale)
+    num_layers: Optional[int] = None  # depth cut of the published config
     steps: int = 20
     global_batch: int = 8
     seq_len: int = 128
@@ -58,13 +63,13 @@ class TrainJob:
 
 class Trainer:
     def __init__(self, job: TrainJob, *, data_ds: Optional[Dataset] = None,
-                 ckpt: Optional[CheckpointManager] = None) -> None:
+                 ckpt: Optional[CheckpointManager] = None,
+                 mesh: Optional[Mesh] = None) -> None:
+        """``mesh`` defaults to all local devices as (data, model)."""
         self.job = job
-        cfg = get_arch(job.arch)
-        if job.smoke:
-            cfg = reduce_for_smoke(cfg)
+        cfg = job_config(job.arch, smoke=job.smoke, num_layers=job.num_layers)
         self.cfg = cfg
-        self.mesh = make_local_mesh(model_axis=job.model_axis)
+        self.mesh = mesh or make_local_mesh(model_axis=job.model_axis)
         self.rules = make_rules("train")
         self.model = build_model(cfg, shard_fn=make_shard_fn(self.mesh,
                                                              self.rules))
@@ -123,21 +128,30 @@ class Trainer:
         return iter(DeviceFeeder(with_extras(), shardings))
 
     # ------------------------------------------------------------------ run
-    def run(self, *, restore: bool = True) -> Dict[str, Any]:
+    def initial_state(self, *, restore: bool = True) -> Tuple[Any, int]:
+        """(state, step): the latest checkpoint if ``restore`` and one
+        exists, else a fresh state.  Either is placed in the state's
+        shardings on this mesh."""
         job = self.job
         state_specs = train_state_specs(self.model, self.opt,
                                         grad_compress=job.grad_compress)
         shardings = sharding_for_specs(state_specs, self.mesh, self.rules)
-        start_step = 0
         if restore and self.ckpt.latest_step() is not None:
             from repro.models.param import abstract
             state = self.ckpt.restore(abstract(state_specs),
                                       shardings=shardings)
             start_step = self.ckpt.latest_step()
             print(f"[restore] resumed from step {start_step}")
-        else:
-            state = init_state(self.model, self.opt, jax.random.PRNGKey(job.seed),
-                               grad_compress=job.grad_compress)
+            return state, start_step
+        # built directly in its shardings: nothing lands on one device
+        init = functools.partial(init_state, self.model, self.opt,
+                                 grad_compress=job.grad_compress)
+        return jax.jit(init, out_shardings=shardings)(
+            jax.random.PRNGKey(job.seed)), 0
+
+    def run(self, *, restore: bool = True) -> Dict[str, Any]:
+        job = self.job
+        state, start_step = self.initial_state(restore=restore)
         batches = self._batches()
         step = start_step
         with self.mesh:
@@ -172,6 +186,7 @@ def main() -> None:
     ap.add_argument("--arch", default="gemma-2b", choices=sorted(ARCHS))
     ap.add_argument("--smoke", action="store_true", default=True)
     ap.add_argument("--full", dest="smoke", action="store_false")
+    ap.add_argument("--num-layers", type=int, default=None)
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=128)
@@ -183,7 +198,8 @@ def main() -> None:
     ap.add_argument("--tql", default=None)
     ap.add_argument("--model-axis", type=int, default=1)
     args = ap.parse_args()
-    job = TrainJob(arch=args.arch, smoke=args.smoke, steps=args.steps,
+    job = TrainJob(arch=args.arch, smoke=args.smoke,
+                   num_layers=args.num_layers, steps=args.steps,
                    global_batch=args.global_batch, seq_len=args.seq_len,
                    microbatches=args.microbatches,
                    grad_compress=args.grad_compress,
@@ -192,6 +208,8 @@ def main() -> None:
                    fail_at=tuple(args.fail_at), tql_filter=args.tql,
                    model_axis=args.model_axis)
     from repro.distributed import HostFailure, run_resilient
+
+    use_compile_cache()
 
     ckpt = CheckpointManager(MemoryProvider(), keep=3)
     trainer_box = {}
@@ -208,9 +226,11 @@ def main() -> None:
 
     result = run_resilient(make_runner, max_restarts=3,
                            on_restart=lambda n, e: print(f"[restart {n}] {e}"))
+    dev = jax.devices()[0]
     print(f"done: final_step={result['final_step']} "
           f"restarts={result['restarts']} "
-          f"final_loss={trainer_box['out']['final_loss']:.4f}")
+          f"final_loss={trainer_box['out']['final_loss']:.4f} "
+          f"on {len(jax.devices())}x {dev.platform} {dev.device_kind}")
 
 
 if __name__ == "__main__":

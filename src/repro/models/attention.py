@@ -109,10 +109,17 @@ def _block_mask(q_pos, k_pos, window: int):
 
 
 def _online_block(acc, m, l, q, k, v, mask, scale):
-    """One (q-block × kv-block) online-softmax update. fp32 stats."""
+    """One (q-block × kv-block) online-softmax update. fp32 stats.
+
+    The running max only shifts exponents and cancels in acc / l, so it
+    carries no gradient.  Differentiating it would also be unsafe: the
+    reduce_max VJP divides by the count of entries equal to the max, which
+    is 0 when the backward pass recomputes ``s`` in a different fusion and
+    rounding (full remat on the TPU), and so turns every q/k gradient NaN.
+    """
     s = jnp.einsum("bqgnd,bkgd->bgnqk", q, k).astype(jnp.float32) * scale
     s = s + mask[None, None, None, :, :]
-    m_new = jnp.maximum(m, s.max(axis=-1))
+    m_new = jax.lax.stop_gradient(jnp.maximum(m, s.max(axis=-1)))
     p = jnp.exp(s - m_new[..., None])
     corr = jnp.exp(m - m_new)
     l_new = l * corr + p.sum(axis=-1)

@@ -151,6 +151,28 @@ def test_trainer_with_tql_filter_and_compression():
     assert np.isfinite(out["final_loss"])
 
 
+def test_job_config_cuts_depth_only():
+    from repro.configs import get_arch, job_config
+    full = get_arch("starcoder2-3b")
+    cut = job_config("starcoder2-3b", smoke=False, num_layers=4)
+    assert cut.num_layers == 4
+    assert cut.with_(num_layers=full.num_layers) == full
+    assert job_config("starcoder2-3b", smoke=False) == full
+    with pytest.raises(ValueError):
+        job_config("starcoder2-3b", smoke=True, num_layers=4)
+
+
+def test_device_feeder_refuses_key_without_sharding():
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.data import DeviceFeeder
+    from repro.launch.mesh import make_local_mesh
+    sh = NamedSharding(make_local_mesh(), P())
+    batch = {"tokens": np.zeros((2, 4), np.int32),
+             "extra": np.zeros((2,), np.float32)}
+    with pytest.raises(KeyError, match="extra"):
+        next(iter(DeviceFeeder(iter([batch]), {"tokens": sh})))
+
+
 def test_serve_generates_tokens():
     from repro.launch.serve import Server, ServeJob
     job = ServeJob(arch="gemma-2b", batch=2, prompt_len=8, max_new_tokens=6)
@@ -164,6 +186,20 @@ def test_serve_generates_tokens():
     # greedy decode is deterministic
     out2 = Server(job).generate(prompts)
     np.testing.assert_array_equal(out, out2)
+
+
+def test_serve_compiles_cache_init_once_per_size():
+    from repro.launch.serve import Server, ServeJob
+    job = ServeJob(arch="gemma-2b", batch=2, prompt_len=4, max_new_tokens=2)
+    srv = Server(job)
+    prompts = np.zeros((2, 4), np.int32)
+    srv.generate(prompts)
+    init = srv._cache_inits[(2, 6)]
+    srv.generate(prompts)
+    assert srv._cache_inits == {(2, 6): init}
+    assert init._cache_size() == 1
+    srv.generate(prompts, max_new_tokens=3)
+    assert set(srv._cache_inits) == {(2, 6), (2, 7)}
 
 
 # ---------------------------------------------- multi-device collective path
@@ -209,3 +245,39 @@ def test_quantized_allreduce_and_elastic_restore_multidevice():
                        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "MULTIDEV_OK" in r.stdout
+
+
+SHARDED_INIT_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax
+    from repro.distributed import sharding_for_specs
+    from repro.launch.steps import train_state_specs
+    from repro.launch.train import Trainer, TrainJob
+
+    t = Trainer(TrainJob(arch="gemma-2b", global_batch=4, seq_len=32,
+                         num_docs=4))
+    assert dict(t.mesh.shape) == {"data": 4, "model": 1}, t.mesh.shape
+    state, step = t.initial_state(restore=False)
+    assert step == 0
+    want = sharding_for_specs(train_state_specs(t.model, t.opt), t.mesh,
+                              t.rules)
+    leaves = jax.tree_util.tree_leaves(state)
+    for got, exp in zip(leaves, jax.tree_util.tree_leaves(want)):
+        assert got.sharding.is_equivalent_to(exp, got.ndim), (got.sharding, exp)
+    # FSDP: the embedding's d_model axis is split over the 4 devices
+    emb = state["params"]["embed"]
+    assert len(emb.sharding.device_set) == 4
+    assert {s.data.shape for s in emb.addressable_shards} == {
+        (emb.shape[0], emb.shape[1] // 4)}
+    m = state["opt"]["m"]["embed"]
+    assert m.sharding.is_equivalent_to(emb.sharding, m.ndim)
+    print("SHARDED_INIT_OK")
+""")
+
+
+def test_trainer_initialises_state_in_fsdp_shardings():
+    r = subprocess.run([sys.executable, "-c", SHARDED_INIT_SCRIPT],
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "SHARDED_INIT_OK" in r.stdout

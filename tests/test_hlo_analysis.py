@@ -5,6 +5,7 @@ multiplication on scans, slice-aware traffic, collective extraction."""
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +13,8 @@ import pytest
 
 from repro.launch.hlo_analysis import analyze, parse_hlo
 from repro.launch.roofline import extract_cost
+
+ROOT = Path(__file__).resolve().parents[1]
 
 
 def test_matmul_flops_match_cost_analysis():
@@ -65,6 +68,19 @@ def test_remat_train_step_flops_in_expected_band():
     assert got.hbm_bytes < 600e6
 
 
+def test_roofline_refuses_unknown_device_kind():
+    from repro.launch.mesh import peaks
+    from repro.launch.roofline import Roofline, TARGET_KIND
+    rl = Roofline(arch="a", shape="s", mesh="single", chips=1,
+                  flops_per_device=1.0, bytes_per_device=1.0,
+                  collective_bytes=0.0, collective_breakdown={},
+                  peak_memory_per_device=0.0, model_flops_total=1.0)
+    assert rl.to_json()["device_kind"] == TARGET_KIND
+    assert rl.compute_s == 1.0 / peaks(TARGET_KIND)["peak_flops_bf16"]
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("TPU v0")
+
+
 def test_parse_hlo_structures():
     a = jnp.zeros((64, 64), jnp.float32)
     c = jax.jit(lambda a: jnp.tanh(a @ a).sum()).lower(a).compile()
@@ -101,7 +117,7 @@ MULTIDEV = textwrap.dedent("""
 
 def test_collectives_detected_on_sharded_program():
     r = subprocess.run([sys.executable, "-c", MULTIDEV], capture_output=True,
-                       text=True, timeout=300, cwd="/root/repo")
+                       text=True, timeout=300, cwd=ROOT)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "HLO_COLLECTIVES_OK" in r.stdout
 
@@ -115,16 +131,18 @@ def test_dryrun_cell_end_to_end():
     """One real dry-run cell: lower+compile on 256 host devices, JSON out."""
     import json
     import os
-    from pathlib import Path
     env = dict(os.environ, PYTHONPATH="src")
-    r = subprocess.run(DRYRUN_CELL, capture_output=True, text=True,
-                       timeout=900, cwd="/root/repo", env=env)
-    assert r.returncode == 0, r.stderr[-2000:]
-    out = Path("/root/repo/experiments/dryrun/"
-               "granite-moe-1b-a400m__decode_32k__single__pytest.json")
-    d = json.loads(out.read_text())
-    assert d["status"] == "OK"
-    assert d["chips"] == 256
-    assert d["roofline"]["flops_per_device"] > 0
-    assert d["memory_analysis"]["alias_bytes"] > 0   # cache donation aliased
-    out.unlink()
+    out = (ROOT / "experiments" / "dryrun" /
+           "granite-moe-1b-a400m__decode_32k__single__pytest.json")
+    try:
+        r = subprocess.run(DRYRUN_CELL, capture_output=True, text=True,
+                           timeout=900, cwd=ROOT, env=env)
+        assert r.returncode == 0, r.stderr[-2000:]
+        d = json.loads(out.read_text())
+        assert d["status"] == "OK"
+        assert d["chips"] == 256
+        assert d["roofline"]["flops_per_device"] > 0
+        assert d["roofline"]["device_kind"] == "TPU v5 lite"
+        assert d["memory_analysis"]["alias_bytes"] > 0   # cache donation aliased
+    finally:
+        out.unlink(missing_ok=True)

@@ -1,5 +1,7 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp oracle."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -143,3 +145,34 @@ def test_xla_blockwise_attention_matches_ref(rng):
                                atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(got_pairs), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("pairs", [False, True])
+def test_xla_blockwise_attention_grads_match_ref_without_max_vjp(pairs, rng):
+    """Gradients of the XLA train path equal the oracle's, and the backward
+    holds no equality test against the score max: that reduce_max VJP divides
+    by the count of maxima, which is 0 (so NaN) when a remat backward
+    recomputes the scores with different rounding, as it does on the TPU."""
+    from repro.models.attention import blockwise_attention
+    B, S, H, Hkv, D, blk = 1, 64, 4, 2, 16, 16
+    q = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((B, S, Hkv, D)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((B, S, Hkv, D)), jnp.float32)
+
+    def f(q_, k_, v_):
+        out = blockwise_attention(q_, k_, v_, scale=0.25, window=24,
+                                  q_block=blk, kv_block=blk, pairs=pairs)
+        return (out ** 2).sum()
+
+    def f_ref(q_, k_, v_):
+        out = ref_attention(q_, k_, v_, causal=True, window=24, scale=0.25)
+        return (out ** 2).sum()
+
+    grad = jax.grad(f, argnums=(0, 1, 2))
+    for got, want in zip(grad(q, k, v),
+                         jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+    # the scores' equality mask would print as ``bool[..., blk, blk] = eq``
+    text = str(jax.make_jaxpr(grad)(q, k, v))
+    assert not re.search(rf"bool\[[0-9,]*{blk},{blk}\] = eq ", text)
