@@ -131,7 +131,7 @@ def _deeplake_run(images, steps: int):
     return compute, s3b, loader
 
 
-def main(smoke: bool = False, trace_out: str | None = None) -> List[str]:
+def main(smoke: bool = False) -> List[str]:
     n_images = 240 if smoke else N_IMAGES
     steps = 12 if smoke else STEPS
     lines = []
@@ -267,8 +267,8 @@ def main(smoke: bool = False, trace_out: str | None = None) -> List[str]:
     # traced re-run: the tracing layer must not perturb the measured IO —
     # the traced run's simulated seconds must match the untraced run within
     # 5% (deterministic cost model; only the span bookkeeping differs).
-    # Runs in smoke (gate) or when a trace artifact was requested.
-    if smoke or trace_out:
+    # Runs in smoke (gate).
+    if smoke:
         with telemetry.tracing() as tr:
             compute_t, s3t, loader_t = _deeplake_run(images, steps)
         sim_u = s3b.stats["sim_seconds"]
@@ -276,17 +276,12 @@ def main(smoke: bool = False, trace_out: str | None = None) -> List[str]:
         lines.append(row("fig6_trace_overhead", abs(sim_t - sim_u) * 1e6,
                          f"untraced{sim_u:.3f}s_traced{sim_t:.3f}s_"
                          f"spans{len(tr.events())}"))
-        if smoke:
-            assert abs(sim_t - sim_u) <= max(TRACE_OVERHEAD_TOL * sim_u,
-                                             TRACE_OVERHEAD_FLOOR_S), (
-                f"traced sim {sim_t:.3f}s deviates from untraced "
-                f"{sim_u:.3f}s beyond {TRACE_OVERHEAD_TOL:.0%}")
-            assert tr.count("scan.group") > 0, \
-                "traced run produced no scan.group spans"
-        if trace_out:
-            tr.write_chrome(trace_out)
-            lines.append(row("fig6_trace_artifact", len(tr.events()),
-                             trace_out))
+        assert abs(sim_t - sim_u) <= max(TRACE_OVERHEAD_TOL * sim_u,
+                                         TRACE_OVERHEAD_FLOOR_S), (
+            f"traced sim {sim_t:.3f}s deviates from untraced "
+            f"{sim_u:.3f}s beyond {TRACE_OVERHEAD_TOL:.0%}")
+        assert tr.count("scan.group") > 0, \
+            "traced run produced no scan.group spans"
 
     io_report.record("fig6_streaming_train", {
         "stall_attribution": stall_attr,
@@ -311,8 +306,4 @@ def main(smoke: bool = False, trace_out: str | None = None) -> List[str]:
 if __name__ == "__main__":
     import sys
 
-    argv = sys.argv[1:]
-    out = None
-    if "--trace-out" in argv:
-        out = argv[argv.index("--trace-out") + 1]
-    print("\n".join(main(smoke="--smoke" in argv, trace_out=out)))
+    print("\n".join(main(smoke="--smoke" in sys.argv[1:])))

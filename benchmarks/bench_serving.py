@@ -14,17 +14,16 @@ Four gates, all under ``--smoke`` in ``scripts/check.sh``:
 (d) **cache hit** — a repeat query performs zero planner work
     (``tql.plans`` counter frozen) and zero storage requests.
 
-A traced re-run must keep simulated IO seconds within 5% of the
-untraced run and emit ``serve.*`` spans into the Chrome trace artifact
-(``--trace-out``).  Each run records a ``serving`` datapoint in
-``BENCH_io.json``.
+Under ``--smoke`` a traced re-run must keep simulated IO seconds within
+5% of the untraced run and record ``serve.*`` spans.  Each run records a
+``serving`` datapoint in ``BENCH_io.json``.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 import numpy as np
 
@@ -85,7 +84,7 @@ def _storm(svc: QueryService, queries: List[str]) -> tuple:
     return res, lat
 
 
-def main(smoke: bool = False, trace_out: Optional[str] = None) -> List[str]:
+def main(smoke: bool = False) -> List[str]:
     lines: List[str] = []
     base = _build_base()
     datapoint: Dict[str, Dict[str, float]] = {}
@@ -188,8 +187,8 @@ def main(smoke: bool = False, trace_out: Optional[str] = None) -> List[str]:
     datapoint["cache_hit"] = {"requests": 0,
                               "latency_s": float(t.elapsed)}
 
-    # -------------------------- tracing overhead + serve.* span artifact
-    if smoke or trace_out:
+    # ----------------------------------- tracing overhead + serve.* spans
+    if smoke:
         def traced_workload(provider) -> None:
             svc = QueryService(dl.Dataset(provider), max_concurrent=4,
                                shards=2)
@@ -218,10 +217,6 @@ def main(smoke: bool = False, trace_out: Optional[str] = None) -> List[str]:
             "sim_untraced_s": sim_u, "sim_traced_s": sim_t,
             "serve_admit_spans": tr.count("serve.admit"),
             "serve_shard_spans": tr.count("serve.shard[")}
-        if trace_out:
-            tr.write_chrome(trace_out)
-            lines.append(row("serving_trace_artifact", len(tr.events()),
-                             trace_out))
 
     io_report.record("serving", datapoint)
     return lines
@@ -230,8 +225,4 @@ def main(smoke: bool = False, trace_out: Optional[str] = None) -> List[str]:
 if __name__ == "__main__":
     import sys
 
-    argv = sys.argv[1:]
-    out = None
-    if "--trace-out" in argv:
-        out = argv[argv.index("--trace-out") + 1]
-    print("\n".join(main(smoke="--smoke" in argv, trace_out=out)))
+    print("\n".join(main(smoke="--smoke" in sys.argv[1:])))

@@ -1,9 +1,10 @@
 """End-to-end telemetry: span tracing, unified metrics, stall attribution.
 
-This module is the single observability layer for the lakehouse. It has
-three parts, all designed to be compiled out by default: when tracing is
-disabled (the default) a span call returns a shared no-op context manager
-and allocates nothing, and metric counters are plain lock-guarded adds.
+This module is the single observability layer for the lakehouse and the
+accelerator path it feeds. It has three parts, all designed to be compiled
+out by default: when tracing is disabled (the default) a span call returns
+a shared no-op context manager and allocates nothing, and metric counters
+are plain lock-guarded adds.
 
 Span naming scheme
 ------------------
@@ -34,34 +35,40 @@ spans embed the group/unit index in brackets. The wired-in names:
     serve.flight_wait               follower waiting on a single-flight leader
     serve.shard[k]                  one shard worker of a parallel chunk-group
                                     scan (args: groups)
+    feed.pack                       TokenBatcher cutting one (B, S+1) block out
+                                    of its buffer into a batch (not the wait
+                                    on the loader: ``loader.stall``)
+    feed.h2d                        DeviceFeeder issuing the device_put of one
+                                    batch, on its producer thread (args: bytes)
+    feed.wait                       DeviceFeeder's consumer blocked on the
+                                    queue for the next device batch
+    gen.generate                    one whole Server.generate call
+                                    (args: batch, prompt_len, new)
+    gen.absorb                      its prompt loop, through block_until_ready
+    gen.token                       sampling one generated token and fetching
+                                    its ids; ends when the host holds them
 
 ``Tracer.report()`` aggregates by name with bracketed indices normalised
 to ``[*]`` so per-query/per-epoch reports stay compact.
 
-Chrome trace JSON schema
-------------------------
-``Tracer.export_chrome()`` returns (and ``write_chrome(path)`` dumps) the
-standard Chrome ``trace_event`` envelope, loadable in chrome://tracing or
-Perfetto:
-
-    {"traceEvents": [
-        {"ph": "M", "pid": 1, "name": "process_name",
-         "args": {"name": "repro-lakehouse"}},
-        {"ph": "X", "pid": 1, "tid": <thread-id>, "name": "scan.group[3].fetch",
-         "cat": "scan", "ts": <start, microseconds>, "dur": <microseconds>,
-         "args": {..., "depth": <nesting depth>, "parent": <parent span name>}},
-        ...
-    ]}
-
-All complete spans use phase ``"X"`` (duration events); ``cat`` is the
-name's first dot-component; ``ts`` is relative to the tracer epoch.
+Profiler mirror
+---------------
+While the tracer is enabled, each span also enters and leaves a
+``jax.profiler.TraceAnnotation`` of the same name on its thread, so the
+spans land in a ``jax.profiler`` trace (``.xplane.pb``, read by Perfetto
+and TensorBoard) on the device trace's clock, where they label what the
+host did while the device ran or idled. ``jax.profiler`` is imported at
+the first traced span after the process has imported JAX; a process that
+never imports JAX (the lake half alone) records spans without it. Outside
+a profiler session an annotation costs a call and records nothing.
 
 Metrics registry
 ----------------
 ``registry()`` returns the process-wide :class:`MetricsRegistry`. Metric
 names are dot-separated (``commit.rebases``, ``storage.wasted_upload_bytes``,
 ``tql.plans``, ``serve.cache.hits`` / ``serve.cache.misses`` /
-``serve.plan_cache.hits``, per-tenant ``serve.tenant.<t>.*``);
+``serve.plan_cache.hits``, per-tenant ``serve.tenant.<t>.*``,
+``gen.decode_calls``: one per decode-step call of ``Server.generate``);
 ``snapshot()`` flattens them to underscore keys (``commit_rebases``) so they
 can be recorded as ``BENCH_io.json`` leaves. ``provider_snapshot(provider)``
 is the one snapshot API the benches share: numeric provider stats merged
@@ -83,8 +90,8 @@ buckets into the fig6 stall decomposition — ``retry_hedge_s``,
 
 from __future__ import annotations
 
-import json
 import re
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -121,34 +128,17 @@ _INDEX_RE = re.compile(r"\[\d+\]")
 class SpanRecord:
     """One finished span: immutable record appended to the tracer buffer."""
 
-    __slots__ = ("name", "cat", "ts", "dur", "tid", "depth", "parent", "args")
+    __slots__ = ("name", "ts", "dur", "tid", "depth", "parent", "args")
 
-    def __init__(self, name: str, cat: str, ts: float, dur: float, tid: int,
+    def __init__(self, name: str, ts: float, dur: float, tid: int,
                  depth: int, parent: Optional[str], args: Dict[str, Any]):
         self.name = name
-        self.cat = cat
         self.ts = ts          # seconds since tracer epoch
         self.dur = dur        # seconds
         self.tid = tid
         self.depth = depth
         self.parent = parent
         self.args = args
-
-    def to_chrome(self, pid: int = 1) -> Dict[str, Any]:
-        args = dict(self.args)
-        args["depth"] = self.depth
-        if self.parent is not None:
-            args["parent"] = self.parent
-        return {
-            "ph": "X",
-            "pid": pid,
-            "tid": self.tid,
-            "name": self.name,
-            "cat": self.cat,
-            "ts": self.ts * 1e6,
-            "dur": self.dur * 1e6,
-            "args": args,
-        }
 
     def __repr__(self) -> str:  # pragma: no cover - debug aid
         return f"SpanRecord({self.name!r}, ts={self.ts:.6f}, dur={self.dur:.6f})"
@@ -175,11 +165,26 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+_annotation_cls: Any = None
+
+
+def _annotation(name: str) -> Any:
+    """A ``jax.profiler.TraceAnnotation`` for ``name``, or None while the
+    process has not imported JAX (no profiler can be running then)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name)
+
 
 class _Span:
-    """A live span; records itself into the tracer buffer on exit."""
+    """A live span; records itself into the tracer buffer on exit, and
+    mirrors itself into the profiler's trace while open."""
 
-    __slots__ = ("tracer", "name", "args", "t0", "depth", "parent")
+    __slots__ = ("tracer", "name", "args", "t0", "depth", "parent", "mirror")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self.tracer = tracer
@@ -188,6 +193,7 @@ class _Span:
         self.t0 = 0.0
         self.depth = 0
         self.parent: Optional[str] = None
+        self.mirror: Any = None
 
     def set(self, **args: Any) -> "_Span":
         """Attach extra args; must be called before the span exits."""
@@ -202,11 +208,16 @@ class _Span:
         self.parent = stack[-1].name if stack else None
         self.depth = len(stack)
         stack.append(self)
+        self.mirror = _annotation(self.name)
+        if self.mirror is not None:
+            self.mirror.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, etype: Any, evalue: Any, tb: Any) -> bool:
         dur = time.perf_counter() - self.t0
+        if self.mirror is not None:
+            self.mirror.__exit__(None, None, None)
         stack = getattr(self.tracer._tls, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()
@@ -251,11 +262,8 @@ class Tracer:
         return _Span(self, name, args)
 
     def _record(self, sp: _Span, dur: float) -> None:
-        name = sp.name
-        dot = name.find(".")
         rec = SpanRecord(
-            name=name,
-            cat=name[:dot] if dot > 0 else name,
+            name=sp.name,
             ts=sp.t0 - self._epoch,
             dur=dur,
             tid=threading.get_ident(),
@@ -291,21 +299,6 @@ class Tracer:
             agg["total_s"] += e.dur
             agg["max_s"] = max(agg["max_s"], e.dur)
         return out
-
-    # -- export ------------------------------------------------------------
-
-    def export_chrome(self, pid: int = 1) -> Dict[str, Any]:
-        events: List[Dict[str, Any]] = [
-            {"ph": "M", "pid": pid, "name": "process_name",
-             "args": {"name": "repro-lakehouse"}},
-        ]
-        events.extend(e.to_chrome(pid) for e in self.events())
-        return {"traceEvents": events}
-
-    def write_chrome(self, path: str, pid: int = 1) -> None:
-        doc = self.export_chrome(pid)
-        with open(path, "w", encoding="utf-8") as fh:
-            json.dump(doc, fh)
 
 
 _TRACER = Tracer()

@@ -3,9 +3,11 @@
 ``TokenBatcher`` packs ragged documents from a Deep Lake view into fixed
 (B, S+1) token blocks (targets = inputs shifted).  ``DeviceFeeder`` turns a
 host batch iterator into sharded global device arrays with DOUBLE BUFFERING:
-the next batch's device_put overlaps the current train step, so at steady
-state the accelerator never waits on H2D — the Fig 6/7 property, carried to
-the device boundary.
+the next batch's device_put overlaps the current train step — the Fig 6/7
+property, carried to the device boundary.  Whether the step still waits on
+its input is measured, not assumed: the ``feed.wait`` span (the benchmark's
+``feed_wait_ms_per_step``) times the consumer blocked on the queue, beside
+``feed.pack`` and ``feed.h2d`` for the producer's two stages.
 
 Multi-host note: each host feeds only its addressable shard of the global
 batch (`host_slice`); in this single-process container that slice is the
@@ -23,6 +25,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
+from repro.core import telemetry
 from repro.core.dataloader import DeepLakeLoader
 from repro.core.views import DatasetView
 
@@ -51,15 +54,16 @@ class TokenBatcher:
             doc = np.asarray(batch["tokens"][0], np.int32).reshape(-1)
             self._buf = np.concatenate([self._buf, doc])
             while len(self._buf) >= need:
-                block = self._buf[:need].reshape(B, S + 1)
-                self._buf = self._buf[need:]
-                out = {"tokens": block[:, :-1],
-                       "targets": block[:, 1:],
-                       "loss_mask": np.ones((B, S), np.float32)}
-                if self.num_codebooks:
-                    k = self.num_codebooks
-                    out["tokens"] = np.stack([block[:, :-1]] * k, axis=1)
-                    out["targets"] = np.stack([block[:, 1:]] * k, axis=1)
+                with telemetry.span("feed.pack"):
+                    block = self._buf[:need].reshape(B, S + 1)
+                    self._buf = self._buf[need:]
+                    out = {"tokens": block[:, :-1],
+                           "targets": block[:, 1:],
+                           "loss_mask": np.ones((B, S), np.float32)}
+                    if self.num_codebooks:
+                        k = self.num_codebooks
+                        out["tokens"] = np.stack([block[:, :-1]] * k, axis=1)
+                        out["targets"] = np.stack([block[:, 1:]] * k, axis=1)
                 yield out
 
 
@@ -78,8 +82,10 @@ class DeviceFeeder:
         if missing:
             raise KeyError(f"batch keys {missing} have no sharding; a key "
                            f"without one would land on a single device")
-        return {k: jax.device_put(v, self.shardings[k])
-                for k, v in batch.items()}
+        with telemetry.span("feed.h2d",
+                            bytes=sum(v.nbytes for v in batch.values())):
+            return {k: jax.device_put(v, self.shardings[k])
+                    for k, v in batch.items()}
 
     def __iter__(self) -> Iterator[Dict[str, jax.Array]]:
         q: "queue.Queue" = queue.Queue(maxsize=self.prefetch)
@@ -98,7 +104,8 @@ class DeviceFeeder:
         t = threading.Thread(target=producer, daemon=True)
         t.start()
         while True:
-            item = q.get()
+            with telemetry.span("feed.wait"):
+                item = q.get()
             if item is DONE:
                 if err:
                     raise err[0]

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, job_config
+from repro.core import telemetry
 from repro.distributed import make_rules, make_shard_fn, sharding_for_specs
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
@@ -83,31 +84,37 @@ class Server:
         new = max_new_tokens or job.max_new_tokens
         B, P = prompts.shape
         total = P + new
-        cache = self._init_cache(B, total)
-        rng = jax.random.PRNGKey(job.seed)
-        out = np.zeros((B, total), np.int32)
-        out[:, :P] = prompts
-        t0 = time.perf_counter()
-        with self.mesh:
-            # prompt absorption token-by-token through the decode path (the
-            # cache layout then matches decode exactly); prefill-step lowering
-            # is exercised separately by the dry-run prefill cells.
-            logits = None
-            for t in range(P):
-                logits, cache = self._decode(self.params, cache,
-                                             jnp.asarray(out[:, t]),
-                                             jnp.int32(t))
-            self.prompt_logits = jax.block_until_ready(logits)
-            self.stats["prefill_s"] += time.perf_counter() - t0
+        calls = telemetry.registry().counter("gen.decode_calls")
+        with telemetry.span("gen.generate", batch=B, prompt_len=P, new=new):
+            cache = self._init_cache(B, total)
+            rng = jax.random.PRNGKey(job.seed)
+            out = np.zeros((B, total), np.int32)
+            out[:, :P] = prompts
             t0 = time.perf_counter()
-            for t in range(P, total):
-                nxt = self._sample(logits, rng, t)
-                out[:, t] = np.asarray(nxt)
-                logits, cache = self._decode(self.params, cache,
-                                             jnp.asarray(out[:, t]),
-                                             jnp.int32(t))
-            self.stats["decode_s"] += time.perf_counter() - t0
-            self.stats["tokens"] += B * new
+            with self.mesh:
+                # prompt absorption token-by-token through the decode path
+                # (the cache layout then matches decode exactly); prefill-step
+                # lowering is exercised separately by the dry-run prefill cells.
+                logits = None
+                with telemetry.span("gen.absorb"):
+                    for t in range(P):
+                        logits, cache = self._decode(self.params, cache,
+                                                     jnp.asarray(out[:, t]),
+                                                     jnp.int32(t))
+                        calls.inc()
+                    self.prompt_logits = jax.block_until_ready(logits)
+                self.stats["prefill_s"] += time.perf_counter() - t0
+                t0 = time.perf_counter()
+                for t in range(P, total):
+                    with telemetry.span("gen.token"):
+                        nxt = self._sample(logits, rng, t)
+                        out[:, t] = np.asarray(nxt)
+                    logits, cache = self._decode(self.params, cache,
+                                                 jnp.asarray(out[:, t]),
+                                                 jnp.int32(t))
+                    calls.inc()
+                self.stats["decode_s"] += time.perf_counter() - t0
+                self.stats["tokens"] += B * new
         return out
 
     def _sample(self, logits, rng, t):

@@ -248,6 +248,7 @@ def gqa_attend(q, k, v, cfg, *, window: int = 0, impl: str = "xla",
                                pairs=(impl == "xla_pairs"), q_offset=q_offset)
 
 
+@jax.named_scope("attention")
 def gqa_train(params, x, positions, cfg, *, window: int = 0,
               impl: str = "xla") -> jax.Array:
     q, k, v = gqa_qkv(params, x, positions, cfg)
@@ -255,6 +256,7 @@ def gqa_train(params, x, positions, cfg, *, window: int = 0,
     return jnp.einsum("bshk,hkd->bsd", out, params["wo"])
 
 
+@jax.named_scope("attention")
 def gqa_prefill(params, x, positions, cfg, *, window: int = 0,
                 impl: str = "xla"):
     """Forward + return the KV cache this segment produces."""
@@ -265,6 +267,7 @@ def gqa_prefill(params, x, positions, cfg, *, window: int = 0,
     return jnp.einsum("bshk,hkd->bsd", out, params["wo"]), (k, v)
 
 
+@jax.named_scope("attention")
 def gqa_decode(params, x, cache_k, cache_v, pos, cfg, *, window: int = 0,
                impl: str = "xla"):
     """One-token decode. x (B,1,d); caches (B,T,Hkv,D); pos () int32.
@@ -281,10 +284,11 @@ def gqa_decode(params, x, cache_k, cache_v, pos, cfg, *, window: int = 0,
     k = apply_rope(k, positions, cfg.rope_theta)
     T = cache_k.shape[1]
     slot = (pos % window) if window else pos
-    cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k.astype(cache_k.dtype),
-                                                  slot, axis=1)
-    cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v.astype(cache_v.dtype),
-                                                  slot, axis=1)
+    with jax.named_scope("kv_write"):
+        cache_k = jax.lax.dynamic_update_slice_in_dim(
+            cache_k, k.astype(cache_k.dtype), slot, axis=1)
+        cache_v = jax.lax.dynamic_update_slice_in_dim(
+            cache_v, v.astype(cache_v.dtype), slot, axis=1)
     if impl in ("pallas", "pallas_interpret"):
         from repro.kernels.decode_attention import ops as da_ops
         out = da_ops.decode_attention(
@@ -335,6 +339,7 @@ def mla_latents(params, x, positions, cfg):
     return c_kv, k_rope
 
 
+@jax.named_scope("attention")
 def mla_train(params, x, positions, cfg, *, impl: str = "xla") -> jax.Array:
     """Training path: expand K/V from latents, run standard attention."""
     m = cfg.mla
@@ -356,12 +361,14 @@ def mla_train(params, x, positions, cfg, *, impl: str = "xla") -> jax.Array:
     return jnp.einsum("bshk,hkd->bsd", out, params["wo"])
 
 
+@jax.named_scope("attention")
 def mla_prefill(params, x, positions, cfg, *, impl: str = "xla"):
     out = mla_train(params, x, positions, cfg, impl=impl)
     c_kv, k_rope = mla_latents(params, x, positions, cfg)
     return out, (c_kv, k_rope)
 
 
+@jax.named_scope("attention")
 def mla_decode(params, x, cache_ckv, cache_kr, pos, cfg):
     """Absorbed single-token MLA decode: attend in the 512-d latent space.
 
@@ -373,10 +380,11 @@ def mla_decode(params, x, cache_ckv, cache_kr, pos, cfg):
     positions = jnp.full((B, 1), pos, jnp.int32)
     q_nope, q_rope = mla_project_q(params, x, positions, cfg)   # (B,1,H,*)
     c_kv, k_rope = mla_latents(params, x, positions, cfg)       # (B,1,r),(B,1,rd)
-    cache_ckv = jax.lax.dynamic_update_slice_in_dim(
-        cache_ckv, c_kv.astype(cache_ckv.dtype), pos, axis=1)
-    cache_kr = jax.lax.dynamic_update_slice_in_dim(
-        cache_kr, k_rope.astype(cache_kr.dtype), pos, axis=1)
+    with jax.named_scope("kv_write"):
+        cache_ckv = jax.lax.dynamic_update_slice_in_dim(
+            cache_ckv, c_kv.astype(cache_ckv.dtype), pos, axis=1)
+        cache_kr = jax.lax.dynamic_update_slice_in_dim(
+            cache_kr, k_rope.astype(cache_kr.dtype), pos, axis=1)
     # absorb W_uk into q:  q_abs (B,H,r)
     q_abs = jnp.einsum("bhk,rhk->bhr", q_nope[:, 0], params["w_uk"])
     s = jnp.einsum("bhr,btr->bht", q_abs, cache_ckv).astype(jnp.float32)

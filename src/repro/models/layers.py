@@ -20,6 +20,7 @@ def rmsnorm_spec(dim: int) -> ParamSpec:
     return ParamSpec((dim,), (None,), init="ones", dtype="float32")
 
 
+@jax.named_scope("norm")
 def rmsnorm(scale: jax.Array, x: jax.Array, eps: float = 1e-6) -> jax.Array:
     dt = x.dtype
     xf = x.astype(jnp.float32)
@@ -59,6 +60,7 @@ def mlp_specs(d_model: int, d_ff: int, variant: str, dtype: str,
     return specs
 
 
+@jax.named_scope("mlp")
 def mlp(params: dict, x: jax.Array, variant: str) -> jax.Array:
     h = x @ params["wi"]
     if variant == "silu_glu":
@@ -78,6 +80,7 @@ def embedding_spec(vocab: int, d_model: int, dtype: str) -> ParamSpec:
                      scale=1.0, dtype=dtype)
 
 
+@jax.named_scope("embed")
 def embed(table: jax.Array, tokens: jax.Array) -> jax.Array:
     return jnp.take(table, tokens, axis=0)
 
@@ -91,6 +94,7 @@ def unembed_logits(table_or_head: jax.Array, h: jax.Array,
 
 
 # ------------------------------------------------------------------- loss
+@jax.named_scope("head")
 def softmax_cross_entropy(logits: jax.Array, targets: jax.Array,
                           mask: Optional[jax.Array] = None) -> jax.Array:
     """Token-mean CE over possibly vocab-sharded logits (GSPMD inserts the

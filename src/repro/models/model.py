@@ -205,6 +205,7 @@ class Model:
         return self.shard(h, ("batch", None, None))
 
     # --------------------------------------------------------------- embed
+    @jax.named_scope("embed")
     def _embed_tokens(self, params, batch):
         cfg = self.cfg
         tokens = batch["tokens"]
@@ -221,6 +222,7 @@ class Model:
             h = jnp.concatenate([img, h[:, cfg.num_image_tokens:]], axis=1)
         return self.shard(h, ("batch", None, None))
 
+    @jax.named_scope("head")
     def _logits(self, params, h):
         cfg = self.cfg
         hf = h.astype(jnp.float32)
@@ -477,14 +479,15 @@ class Model:
         Returns (logits, new_cache)."""
         cfg = self.cfg
         B = tokens.shape[0]
-        if cfg.num_codebooks:
-            h = None
-            for k in range(cfg.num_codebooks):
-                e = embed(params["embed"][k], tokens[:, k][:, None])
-                h = e if h is None else h + e
-        else:
-            h = embed(params["embed"], tokens[:, None])     # (B,1,d)
-        h = h * jnp.asarray(np.sqrt(cfg.d_model), h.dtype)
+        with jax.named_scope("embed"):
+            if cfg.num_codebooks:
+                h = None
+                for k in range(cfg.num_codebooks):
+                    e = embed(params["embed"][k], tokens[:, k][:, None])
+                    h = e if h is None else h + e
+            else:
+                h = embed(params["embed"], tokens[:, None])     # (B,1,d)
+            h = h * jnp.asarray(np.sqrt(cfg.d_model), h.dtype)
 
         def dense_step(p, hh, ck, cv, kind):
             window = cfg.sliding_window if kind == "L" else 0
@@ -792,6 +795,7 @@ class Model:
         raise ValueError(cfg.family)
 
 
+@jax.named_scope("ssm")
 def ssm_lib_prefill(p, hn, cfg, attn_impl):
     """Mamba2 prefill: forward + (final ssm state, conv tail)."""
     s = cfg.ssm

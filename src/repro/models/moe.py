@@ -68,6 +68,7 @@ def _route(params, x2d: jax.Array, cfg) -> Tuple[jax.Array, jax.Array, jax.Array
     return idx, w, aux
 
 
+@jax.named_scope("moe")
 def moe_apply(params, x: jax.Array, cfg,
               shard=lambda x, axes=None: x) -> Tuple[jax.Array, jax.Array]:
     """x (B, S, d) -> (out (B, S, d), aux_loss scalar).

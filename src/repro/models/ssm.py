@@ -178,6 +178,7 @@ def ssd_reference(x, dt, A, Bm, Cm, init_state=None):
 
 
 # ------------------------------------------------------------- layer fwd
+@jax.named_scope("ssm")
 def mamba2_forward(params, u: jax.Array, cfg, *, impl: str = "xla",
                    init_state=None, return_state: bool = False):
     """Full Mamba2 layer: in_proj -> conv -> SSD -> gated norm -> out_proj."""
@@ -209,6 +210,7 @@ def mamba2_forward(params, u: jax.Array, cfg, *, impl: str = "xla",
     return out
 
 
+@jax.named_scope("ssm")
 def mamba2_decode_step(params, u: jax.Array, ssm_state: jax.Array,
                        conv_state: jax.Array, cfg):
     """One-token decode. u (B,1,d); ssm_state (B,nh,N,P);

@@ -43,6 +43,7 @@ class AdamW:
                 "m": tree_map_specs(remap, param_specs),
                 "v": tree_map_specs(remap, param_specs)}
 
+    @jax.named_scope("optimizer")
     def update(self, grads, state: Dict[str, Any], params
                ) -> Tuple[Any, Dict[str, Any], Dict[str, jax.Array]]:
         step = state["step"] + 1
@@ -73,12 +74,14 @@ class AdamW:
             {"grad_norm": gnorm}
 
 
+@jax.named_scope("optimizer")
 def global_norm(tree) -> jax.Array:
     leaves = jax.tree_util.tree_leaves(tree)
     return jnp.sqrt(sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
                         for l in leaves))
 
 
+@jax.named_scope("optimizer")
 def apply_updates(params, updates):
     return jax.tree_util.tree_map(lambda p, u: p + u.astype(p.dtype),
                                   params, updates)

@@ -1,17 +1,22 @@
 """End-to-end telemetry (core/telemetry.py): span tracing, unified
 metrics registry, IO-cause stall attribution.
 
-Covers the PR-8 contract: nested cause-tagged spans with a shared no-op
-disabled path, Chrome trace_event export, the process-wide metrics
-registry mirroring commit/waste counters, the provider's per-cause
-``sim_s_*`` partition invariant, the fig6 stall decomposition summing
-exactly to its total, and traced chaos runs containing the fault-recovery
-spans (``fetch.retry``, ``fetch.hedge``, ``commit.rebase``).
+Covers nested cause-tagged spans with a shared no-op disabled path, their
+mirror into a ``jax.profiler`` trace, the process-wide metrics registry
+mirroring commit/waste counters, the provider's per-cause ``sim_s_*``
+partition invariant, the fig6 stall decomposition summing exactly to its
+total, traced chaos runs containing the fault-recovery spans
+(``fetch.retry``, ``fetch.hedge``, ``commit.rebase``), and the spans and
+counter of the accelerator path's feed (``feed.*``) and generation
+(``gen.*``, ``gen.decode_calls``).
 """
 
-import json
+import os
+import subprocess
+import sys
 import threading
 import time
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -36,6 +41,23 @@ def test_disabled_path_is_shared_noop():
     with s1:
         s1.set(anything=1)  # no-op, chainable
     assert tr.events() == []
+
+
+def test_lake_half_imports_and_traces_without_jax():
+    """``repro.core`` and its tracer never import JAX: a lake-only process
+    records spans, and the profiler mirror waits until JAX is imported."""
+    code = ("import sys\n"
+            "from repro.core import telemetry\n"
+            "with telemetry.tracing() as tr:\n"
+            "    with telemetry.span('query.plan'):\n"
+            "        pass\n"
+            "assert tr.count('query.plan') == 1\n"
+            "assert telemetry.span('x') is telemetry.null_span()\n"
+            "assert not [m for m in sys.modules if m.split('.')[0] in "
+            "('jax', 'jaxlib')], 'repro.core imported jax'\n")
+    src = str(Path(telemetry.__file__).resolve().parents[2])
+    subprocess.run([sys.executable, "-c", code], check=True,
+                   env=dict(os.environ, PYTHONPATH=src))
 
 
 def test_span_nesting_parent_depth_and_ordering():
@@ -78,32 +100,33 @@ def test_report_normalises_group_indices():
     assert rep["scan.group[*].fetch"]["total_s"] >= 0.0
 
 
-def test_chrome_export_shape():
-    with tracing() as tr:
-        with telemetry.span("query.plan", effective=2):
-            pass
-    doc = tr.export_chrome()
-    evs = doc["traceEvents"]
-    assert evs[0] == {"ph": "M", "pid": 1, "name": "process_name",
-                      "args": {"name": "repro-lakehouse"}}
-    (x,) = [e for e in evs if e["ph"] == "X"]
-    assert x["name"] == "query.plan" and x["cat"] == "query"
-    assert x["tid"] == threading.get_ident()
-    assert x["ts"] >= 0 and x["dur"] >= 0          # microseconds
-    assert x["args"]["effective"] == 2 and x["args"]["depth"] == 0
-    # round-trips through json
-    json.dumps(doc)
+def test_traced_span_lands_on_the_profiler_host_plane(tmp_path):
+    """A span opened while tracing, inside a ``jax.profiler`` trace, is an
+    event of the same name on the trace's host plane; one opened with
+    tracing off is not."""
+    import jax
+    from jax.profiler import ProfileData
 
-
-def test_write_chrome_artifact(tmp_path):
-    path = tmp_path / "trace.json"
-    with tracing() as tr:
-        with telemetry.span("scan.group[2].deliver", rows=4):
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.span("feed.wait"):       # tracing off: no event
             pass
-    tr.write_chrome(str(path))
-    doc = json.loads(path.read_text())
-    names = [e["name"] for e in doc["traceEvents"] if e.get("ph") == "X"]
-    assert names == ["scan.group[2].deliver"]
+        with tracing() as tr:
+            with telemetry.span("feed.pack"):
+                with telemetry.gspan(2, "deliver", rows=4):
+                    time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    host = [e for p in ProfileData.from_file(str(path)).planes
+            if not p.name.startswith("/device:")
+            for line in p.lines for e in line.events]
+    names = {e.name for e in host}
+    assert {"feed.pack", "scan.group[2].deliver"} <= names
+    assert "feed.wait" not in names
+    inner = next(e for e in host if e.name == "scan.group[2].deliver")
+    assert inner.duration_ns >= 1e6
+    assert tr.count("feed.pack") == 1           # the buffer records it too
 
 
 def test_tracer_thread_safety_and_per_thread_stacks():
@@ -347,3 +370,93 @@ def test_tql_query_spans():
         assert len(view.indices) == 10
     assert tr.count("query.plan") == 1
     assert tr.count("query.where") == 1
+
+
+# ------------------------------------- accelerator path: feed and generate
+def _token_feed(num_docs=10):
+    """A TokenBatcher over a small token lake, and DeviceFeeder shardings
+    for its batches on the local mesh."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.core.views import DatasetView
+    from repro.data import TokenBatcher, build_token_dataset
+    from repro.launch.mesh import make_local_mesh
+
+    ds = build_token_dataset(dl.Dataset(dl.MemoryProvider()),
+                             num_docs=num_docs, doc_len=48, vocab_size=97,
+                             seed=3)
+    batcher = TokenBatcher(DatasetView.full(ds), batch_size=2, seq_len=16,
+                           shuffle=False, num_workers=1)
+    rep = NamedSharding(make_local_mesh(), PartitionSpec())
+    return batcher, {k: rep for k in ("tokens", "targets", "loss_mask")}
+
+
+def test_token_feed_records_pack_h2d_and_wait_spans():
+    """One ``feed.pack`` and one ``feed.h2d`` per batch, one ``feed.wait``
+    per ``next()`` on the feeder (the last one receives its end)."""
+    from repro.data import DeviceFeeder
+
+    batcher, shardings = _token_feed()
+    with tracing() as tr:
+        it = iter(DeviceFeeder(iter(batcher), shardings))
+        got, calls = [], 0
+        while True:
+            calls += 1
+            try:
+                got.append(next(it))
+            except StopIteration:
+                break
+    assert len(got) >= 3
+    assert tr.count("feed.pack") == len(got)
+    assert tr.count("feed.h2d") == len(got)
+    assert tr.count("feed.wait") == calls == len(got) + 1
+    # three (2, 16) arrays of 4-byte elements a batch
+    assert {e.args["bytes"] for e in tr.find("feed.h2d")} == {3 * 2 * 16 * 4}
+    # packing and transfer run on the producer thread, the wait on ours
+    me = threading.get_ident()
+    assert {e.tid for e in tr.find("feed.wait")} == {me}
+    assert me not in {e.tid for e in tr.find("feed.pack") + tr.find("feed.h2d")}
+
+
+@pytest.fixture(scope="module")
+def smoke_server():
+    from repro.launch.serve import Server, ServeJob
+    return Server(ServeJob(arch="starcoder2-3b", batch=2, prompt_len=5,
+                           max_new_tokens=3))
+
+
+def test_generate_records_its_spans_and_decode_calls(smoke_server):
+    """One ``gen.generate`` and one ``gen.absorb`` a call, one
+    ``gen.token`` per generated token, and ``gen.decode_calls`` grows by
+    prompt length plus new tokens."""
+    prompts = np.arange(10, dtype=np.int32).reshape(2, 5)
+    reg0 = registry().snapshot()
+    with tracing() as tr:
+        out = smoke_server.generate(prompts)
+    assert out.shape == (2, 8)
+    assert registry().delta(reg0)["gen_decode_calls"] == 5 + 3
+    (gen,) = tr.find("gen.generate")
+    assert gen.args == {"batch": 2, "prompt_len": 5, "new": 3}
+    (absorb,) = tr.find("gen.absorb")
+    tokens = tr.find("gen.token")
+    assert len(tokens) == 3
+    for e in [absorb] + tokens:
+        assert e.parent == "gen.generate" and e.depth == 1
+        assert gen.ts <= e.ts and e.ts + e.dur <= gen.ts + gen.dur
+    assert absorb.ts + absorb.dur <= min(e.ts for e in tokens)
+
+
+def test_disabled_tracing_leaves_feed_and_generate_unrecorded(smoke_server):
+    """With tracing off the feeder and ``Server.generate`` record no span;
+    the decode-call counter still counts (counters are always on)."""
+    from repro.data import DeviceFeeder
+
+    tr = get_tracer()
+    tr.clear()
+    assert not telemetry.enabled()
+    batcher, shardings = _token_feed()
+    assert sum(1 for _ in DeviceFeeder(iter(batcher), shardings)) >= 3
+    reg0 = registry().snapshot()
+    smoke_server.generate(np.ones((2, 5), np.int32))
+    assert registry().delta(reg0)["gen_decode_calls"] == 5 + 3
+    assert tr.events() == []
