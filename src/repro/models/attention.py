@@ -267,12 +267,39 @@ def gqa_prefill(params, x, positions, cfg, *, window: int = 0,
     return jnp.einsum("bshk,hkd->bsd", out, params["wo"]), (k, v)
 
 
+def _write_position(cache, new, slot, layer):
+    """Write one position ``new`` (B,1,...) into ``cache`` at ``slot`` along
+    its time axis: a (B,T,...) cache, or with ``layer`` that layer of a
+    stacked (L,B,T,...) cache, updated in place (no layer is sliced out)."""
+    new = new.astype(cache.dtype)
+    if layer is None:
+        return jax.lax.dynamic_update_slice_in_dim(cache, new, slot, axis=1)
+    zero = jnp.zeros((), jnp.int32)
+    start = (layer, zero, slot) + (zero,) * (new.ndim - 2)
+    return jax.lax.dynamic_update_slice(cache, new[None], start)
+
+
+def _layer(cache, layer):
+    """The (B,T,...) cache that attention reads: ``cache`` itself, or layer
+    ``layer`` of a stacked one (a read: the stack is not written)."""
+    if layer is None:
+        return cache
+    return jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+
+
 @jax.named_scope("attention")
 def gqa_decode(params, x, cache_k, cache_v, pos, cfg, *, window: int = 0,
-               impl: str = "xla"):
-    """One-token decode. x (B,1,d); caches (B,T,Hkv,D); pos () int32.
+               impl: str = "xla", layer=None):
+    """One-token decode. x (B,1,d); pos () int32.
 
-    Local layers use a ring buffer of size ``window`` (slot = pos % window).
+    The caches are (B,T,Hkv,D), or with ``layer`` (an int32 scalar) the
+    stacked (L,B,T,Hkv,D) caches of every layer.  Exactly one position is
+    written, at (``layer``,) b, slot; the layer's (B,T,Hkv,D) slice is only
+    read, for the two attention products.  A caller that donates the stacked
+    caches (``Model.decode_step`` under ``Server``) gets them back updated
+    in place.  Local layers use a ring buffer of size ``window``
+    (slot = pos % window).  Returns (out, cache_k, cache_v) in the form
+    given.
     """
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
@@ -282,24 +309,23 @@ def gqa_decode(params, x, cache_k, cache_v, pos, cfg, *, window: int = 0,
     positions = jnp.full((x.shape[0], 1), pos, jnp.int32)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-    T = cache_k.shape[1]
     slot = (pos % window) if window else pos
     with jax.named_scope("kv_write"):
-        cache_k = jax.lax.dynamic_update_slice_in_dim(
-            cache_k, k.astype(cache_k.dtype), slot, axis=1)
-        cache_v = jax.lax.dynamic_update_slice_in_dim(
-            cache_v, v.astype(cache_v.dtype), slot, axis=1)
+        cache_k = _write_position(cache_k, k, slot, layer)
+        cache_v = _write_position(cache_v, v, slot, layer)
+    ck, cv = _layer(cache_k, layer), _layer(cache_v, layer)
+    T = ck.shape[1]
     if impl in ("pallas", "pallas_interpret"):
         from repro.kernels.decode_attention import ops as da_ops
         out = da_ops.decode_attention(
-            q[:, 0], cache_k, cache_v, pos=pos, window=window,
+            q[:, 0], ck, cv, pos=pos, window=window,
             interpret=(impl == "pallas_interpret"))[:, None]
     else:
         B, _, H, D = q.shape
-        Hkv = cache_k.shape[2]
+        Hkv = ck.shape[2]
         G = H // Hkv
         qg = q.reshape(B, Hkv, G, D)
-        s = jnp.einsum("bgnd,btgd->bgnt", qg, cache_k).astype(jnp.float32)
+        s = jnp.einsum("bgnd,btgd->bgnt", qg, ck).astype(jnp.float32)
         s = s / np.sqrt(D)
         idx = jnp.arange(T)
         if window:
@@ -309,7 +335,7 @@ def gqa_decode(params, x, cache_k, cache_v, pos, cfg, *, window: int = 0,
             valid = idx <= pos
         s = jnp.where(valid[None, None, None, :], s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bgnt,btgd->bgnd", p.astype(cache_v.dtype), cache_v)
+        out = jnp.einsum("bgnt,btgd->bgnd", p.astype(cv.dtype), cv)
         out = out.reshape(B, 1, H, D)
     proj = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype), params["wo"])
     return proj, cache_k, cache_v
@@ -369,11 +395,13 @@ def mla_prefill(params, x, positions, cfg, *, impl: str = "xla"):
 
 
 @jax.named_scope("attention")
-def mla_decode(params, x, cache_ckv, cache_kr, pos, cfg):
+def mla_decode(params, x, cache_ckv, cache_kr, pos, cfg, *, layer=None):
     """Absorbed single-token MLA decode: attend in the 512-d latent space.
 
     Cache holds (c_kv, k_rope) only — the MLA memory win: r + rd floats per
-    token instead of 2·H·D.
+    token instead of 2·H·D.  The caches are (B,T,r) and (B,T,rd), or with
+    ``layer`` the stacked (L,B,T,...) caches, written at one position and
+    otherwise only read, as in ``gqa_decode``.
     """
     m = cfg.mla
     B = x.shape[0]
@@ -381,19 +409,18 @@ def mla_decode(params, x, cache_ckv, cache_kr, pos, cfg):
     q_nope, q_rope = mla_project_q(params, x, positions, cfg)   # (B,1,H,*)
     c_kv, k_rope = mla_latents(params, x, positions, cfg)       # (B,1,r),(B,1,rd)
     with jax.named_scope("kv_write"):
-        cache_ckv = jax.lax.dynamic_update_slice_in_dim(
-            cache_ckv, c_kv.astype(cache_ckv.dtype), pos, axis=1)
-        cache_kr = jax.lax.dynamic_update_slice_in_dim(
-            cache_kr, k_rope.astype(cache_kr.dtype), pos, axis=1)
+        cache_ckv = _write_position(cache_ckv, c_kv, pos, layer)
+        cache_kr = _write_position(cache_kr, k_rope, pos, layer)
+    ckv, kr = _layer(cache_ckv, layer), _layer(cache_kr, layer)
     # absorb W_uk into q:  q_abs (B,H,r)
     q_abs = jnp.einsum("bhk,rhk->bhr", q_nope[:, 0], params["w_uk"])
-    s = jnp.einsum("bhr,btr->bht", q_abs, cache_ckv).astype(jnp.float32)
-    s = s + jnp.einsum("bhk,btk->bht", q_rope[:, 0], cache_kr).astype(jnp.float32)
+    s = jnp.einsum("bhr,btr->bht", q_abs, ckv).astype(jnp.float32)
+    s = s + jnp.einsum("bhk,btk->bht", q_rope[:, 0], kr).astype(jnp.float32)
     s = s / np.sqrt(m.nope_head_dim + m.rope_head_dim)
-    T = cache_ckv.shape[1]
+    T = ckv.shape[1]
     s = jnp.where((jnp.arange(T) <= pos)[None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    ctx = jnp.einsum("bht,btr->bhr", p.astype(cache_ckv.dtype), cache_ckv)
+    ctx = jnp.einsum("bht,btr->bhr", p.astype(ckv.dtype), ckv)
     out = jnp.einsum("bhr,rhk->bhk", ctx, params["w_uv"])        # (B,H,vd)
     proj = jnp.einsum("bhk,hkd->bd", out.astype(x.dtype), params["wo"])[:, None]
     return proj, cache_ckv, cache_kr

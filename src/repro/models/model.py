@@ -34,6 +34,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.configs.base import ModelConfig
 from . import attention as attn
@@ -476,7 +477,14 @@ class Model:
     # ------------------------------------------------------------------ decode
     def decode_step(self, params, cache, tokens, pos):
         """One token for the whole batch. tokens (B,) or (B,K); pos () int32.
-        Returns (logits, new_cache)."""
+        Returns (logits, new_cache).
+
+        The cache is the pytree of ``cache_specs``.  Stacked attention caches
+        (L,B,T,...) ride in the layer scan's carry: each layer writes its
+        one position into the stack and only reads its (B,T,...) slice, so
+        a donated cache (``Server``) is updated in place, with no layer
+        sliced out and stacked back and no copy of the whole cache.
+        """
         cfg = self.cfg
         B = tokens.shape[0]
         with jax.named_scope("embed"):
@@ -489,11 +497,12 @@ class Model:
                 h = embed(params["embed"], tokens[:, None])     # (B,1,d)
             h = h * jnp.asarray(np.sqrt(cfg.d_model), h.dtype)
 
-        def dense_step(p, hh, ck, cv, kind):
+        def dense_step(p, hh, ck, cv, kind, layer=None):
             window = cfg.sliding_window if kind == "L" else 0
             hn = rmsnorm(p["ln1"], hh, cfg.norm_eps)
             a, ck, cv = attn.gqa_decode(p["attn"], hn, ck, cv, pos, cfg,
-                                        window=window, impl=self.attn_impl)
+                                        window=window, impl=self.attn_impl,
+                                        layer=layer)
             hh = hh + a
             hn = rmsnorm(p["ln2"], hh, cfg.norm_eps)
             if "moe" in p:
@@ -502,9 +511,10 @@ class Model:
                 out = mlp(p["mlp"], hn, cfg.mlp)
             return hh + out, ck, cv
 
-        def mla_step(p, hh, ckv, kr):
+        def mla_step(p, hh, ckv, kr, layer):
             hn = rmsnorm(p["ln1"], hh, cfg.norm_eps)
-            a, ckv, kr = attn.mla_decode(p["attn"], hn, ckv, kr, pos, cfg)
+            a, ckv, kr = attn.mla_decode(p["attn"], hn, ckv, kr, pos, cfg,
+                                         layer=layer)
             hh = hh + a
             hn = rmsnorm(p["ln2"], hh, cfg.norm_eps)
             if "moe" in p:
@@ -513,63 +523,46 @@ class Model:
                 out = mlp(p["mlp"], hn, cfg.mlp)
             return hh + out, ckv, kr
 
+        def scan_layers(hh, blocks, c, kind="G"):
+            """Scan the stacked ``blocks`` with ``c``, their stacked
+            (L,B,T,...) caches ({k, v} or MLA's {ckv, kr}), in the carry.
+
+            The carried stacks keep the layout they enter with: left free,
+            the TPU compiler lays them out for the attention products and
+            copies the whole cache into and out of the loop."""
+            def body(carry, xs):
+                hh, c = carry
+                p, i = xs
+                if "ckv" in c:
+                    hh, ckv, kr = mla_step(p, hh, c["ckv"], c["kr"], i)
+                    c = {"ckv": ckv, "kr": kr}
+                else:
+                    hh, ck, cv = dense_step(p, hh, c["k"], c["v"], kind, i)
+                    c = {"k": ck, "v": cv}
+                c = jax.tree_util.tree_map(lambda x: with_layout_constraint(
+                    x, Layout(tuple(range(x.ndim)))), c)
+                return (hh, c), None
+
+            n = jax.tree_util.tree_leaves(c)[0].shape[0]
+            (hh, c), _ = jax.lax.scan(body, (hh, c),
+                                      (blocks, jnp.arange(n, dtype=jnp.int32)))
+            return hh, c
+
         if cfg.family in ("dense", "audio", "vlm"):
             if cfg.local_global_pattern:
-                h, cache = self._decode_pattern(params, cache, h, pos, dense_step)
+                h, cache = self._decode_pattern(params, cache, h, dense_step,
+                                                scan_layers)
             else:
                 kind = "L" if cfg.sliding_window else "G"
-
-                def body(hh, xs):
-                    p, ck, cv = xs
-                    hh, ck, cv = dense_step(p, hh, ck, cv, kind)
-                    return hh, (ck, cv)
-
-                h, (ck, cv) = jax.lax.scan(
-                    body, h, (params["blocks"], cache["layers"]["k"],
-                              cache["layers"]["v"]))
-                cache = {"layers": {"k": ck, "v": cv}}
+                h, c = scan_layers(h, params["blocks"], cache["layers"], kind)
+                cache = {"layers": c}
         elif cfg.family == "moe":
             new_cache = {}
             if "dense_blocks" in params:
-                if cfg.attention == "mla":
-                    def dbody(hh, xs):
-                        p, ckv, kr = xs
-                        hh, ckv, kr = mla_step(p, hh, ckv, kr)
-                        return hh, (ckv, kr)
-                    h, (ckv, kr) = jax.lax.scan(
-                        dbody, h, (params["dense_blocks"],
-                                   cache["dense_layers"]["ckv"],
-                                   cache["dense_layers"]["kr"]))
-                    new_cache["dense_layers"] = {"ckv": ckv, "kr": kr}
-                else:
-                    def dbody(hh, xs):
-                        p, ck, cv = xs
-                        hh, ck, cv = dense_step(p, hh, ck, cv, "G")
-                        return hh, (ck, cv)
-                    h, (ck, cv) = jax.lax.scan(
-                        dbody, h, (params["dense_blocks"],
-                                   cache["dense_layers"]["k"],
-                                   cache["dense_layers"]["v"]))
-                    new_cache["dense_layers"] = {"k": ck, "v": cv}
-            if cfg.attention == "mla":
-                def mbody(hh, xs):
-                    p, ckv, kr = xs
-                    hh, ckv, kr = mla_step(p, hh, ckv, kr)
-                    return hh, (ckv, kr)
-                h, (ckv, kr) = jax.lax.scan(
-                    mbody, h, (params["moe_blocks"],
-                               cache["moe_layers"]["ckv"],
-                               cache["moe_layers"]["kr"]))
-                new_cache["moe_layers"] = {"ckv": ckv, "kr": kr}
-            else:
-                def mbody(hh, xs):
-                    p, ck, cv = xs
-                    hh, ck, cv = dense_step(p, hh, ck, cv, "G")
-                    return hh, (ck, cv)
-                h, (ck, cv) = jax.lax.scan(
-                    mbody, h, (params["moe_blocks"], cache["moe_layers"]["k"],
-                               cache["moe_layers"]["v"]))
-                new_cache["moe_layers"] = {"k": ck, "v": cv}
+                h, new_cache["dense_layers"] = scan_layers(
+                    h, params["dense_blocks"], cache["dense_layers"])
+            h, new_cache["moe_layers"] = scan_layers(
+                h, params["moe_blocks"], cache["moe_layers"])
             cache = new_cache
         elif cfg.family == "ssm":
             def body(hh, xs):
@@ -588,7 +581,7 @@ class Model:
         logits = self._logits(params, h)[:, 0]
         return logits, cache
 
-    def _decode_pattern(self, params, cache, h, pos, dense_step):
+    def _decode_pattern(self, params, cache, h, dense_step, scan_layers):
         cfg = self.cfg
         pat = cfg.local_global_pattern
 
@@ -618,14 +611,8 @@ class Model:
         new_cache = {"periods_local": {"k": lk, "v": lv},
                      "periods_global": {"k": gk, "v": gv}}
         if "tail" in params:
-            def tail_body(hh, xs):
-                p, ck, cv = xs
-                hh, ck, cv = dense_step(p, hh, ck, cv, pat[0])
-                return hh, (ck, cv)
-            h, (tk, tv) = jax.lax.scan(
-                tail_body, h, (params["tail"], cache["tail"]["k"],
-                               cache["tail"]["v"]))
-            new_cache["tail"] = {"k": tk, "v": tv}
+            h, new_cache["tail"] = scan_layers(h, params["tail"],
+                                               cache["tail"], pat[0])
         return h, new_cache
 
     def _decode_hybrid(self, params, cache, h, pos):

@@ -12,16 +12,20 @@ describe the chip fails them.
 
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import job_config
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.fused_preprocess.fused_preprocess import fused_preprocess_fwd
 from repro.kernels.ssd_scan import ssd_fwd
+from repro.models.model import build_model
+from repro.models.param import abstract
 
 pytestmark = pytest.mark.xdist_group("tpu_compile")
 
@@ -81,3 +85,30 @@ def test_fused_preprocess_compiles_at_224_crop(one_chip):
     _compile(lambda im: fused_preprocess_fwd(im, (16, 16, 224, 224), mean,
                                              std),
              one_chip, ((8, 256, 256, 3), jnp.uint8))
+
+
+def test_decode_step_holds_no_copy_of_the_stacked_cache(one_chip):
+    """Left free, the chip's compiler lays the layer scan's carried KV cache
+    out for the attention products at starcoder2-3b widths, and copies the
+    whole cache into and out of the scan; the donated step must update the
+    stacked cache in place, with no such copy."""
+    model = build_model(job_config("starcoder2-3b", smoke=False,
+                                   num_layers=2))
+    B, T = 8, 64
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(model.abstract_params())
+    cache = on_chip(abstract(model.cache_specs(B, T)))
+    hlo = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, cache, jax.ShapeDtypeStruct((B,), jnp.int32,
+                                            sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    dims = ",".join(map(str, cache["layers"]["k"].shape))
+    assert re.search(rf"= bf16\[{dims}\]\S* dynamic-update-slice\(", hlo)
+    copies = re.findall(rf"%\S+ = bf16\[{dims}\]\S* copy\(", hlo)
+    assert not copies, copies
