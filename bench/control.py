@@ -10,10 +10,11 @@ Kinds, at the cell's own sizes:
 - ``program``: a run of the cell with a one-second window, its numbers as
   the run compares them: the lower readings;
 - ``fp8``: the control, the reference with float8 (e4m3) products, the
-  precision below the configuration's bfloat16.  Train cells compare it
-  with the float32 reference by the numbers the cell compares, from each
-  seed's weights and the first blocks of its corpus under the cell's
-  filter; serve cells take, over the same sample of one round's served
+  precision below the configuration's bfloat16 (the reference module's
+  ``CONTROLS``).  Train cells compare it with the float32 reference by the
+  numbers the cell compares, from each seed's weights and the first blocks
+  of its corpus under the cell's filter, on the cell's devices; serve
+  cells take, over the same sample of one round's served
   requests, the widest logit gap of the tokens it puts first;
 - ``half_batch`` (train cells): the reference with half of each batch's
   rows left out of the loss, the mean taken over the rest (a planted
@@ -37,11 +38,6 @@ import numpy as np
 ROOT = Path(__file__).resolve().parents[1]
 
 
-def _controls():
-    from bench.reference import starcoder2 as ref
-    return {"fp8": ref.dot_fp8}
-
-
 def first_blocks(corpus, keep, B: int, S: int, n: int):
     """The first ``n`` blocks of the corpus's kept documents in order, as
     ``TokenBatcher`` packs them."""
@@ -52,11 +48,13 @@ def first_blocks(corpus, keep, B: int, S: int, n: int):
              "loss_mask": np.ones((B, S), np.float32)} for b in stream]
 
 
-def train_readings(cell, seed: int):
+def train_readings(cell, seed: int, devices=None):
+    """The control's and the half batch's numbers against the reference,
+    which runs over ``devices`` as the cell's own run does."""
     from bench import compare, harness
     from bench.gen.corpus import token_corpus
-    from bench.reference import starcoder2 as ref
 
+    ref = cell.reference
     cfg, traffic = cell.config, cell.traffic
     B, S = cfg["train"]["global_batch"], cfg["train"]["seq_len"]
     corpus = token_corpus(traffic["corpus"], seed, cfg["vocab_size"])
@@ -65,17 +63,19 @@ def train_readings(cell, seed: int):
             else np.ones(len(corpus), bool))
     batches = first_blocks(corpus, keep, B, S, traffic["check_steps"])
     s, opt, js = ref.Sizes.of(cfg), cfg["optimizer"], harness.jax_seed(seed)
-    base = ref.train_steps(s, opt, js, batches, keep_grad=True)
+    base = ref.train_steps(s, opt, js, batches, keep_grad=True,
+                           devices=devices)
     half = [dict(b, loss_mask=np.concatenate(
         [b["loss_mask"][: B // 2], np.zeros_like(b["loss_mask"][B // 2:])]))
         for b in batches]
     moved = compare.moved_leaves(base["grad_norms"])
     out = {}
-    runs = [(k, {"dot": d}) for k, d in _controls().items()]
+    runs = [(k, {"dot": d}) for k, d in ref.CONTROLS.items()]
     runs.append(("half_batch", {}))
     for kind, kw in runs:
         got = ref.train_steps(s, opt, js, half if kind == "half_batch"
-                              else batches, against=base["grad"], **kw)
+                              else batches, against=base["grad"],
+                              devices=devices, **kw)
         out[kind] = {
             "loss_gap": compare.loss_gap(got["losses"], base["losses"]),
             "grad_gap": compare.norm_gap(got["grad_norms"],
@@ -92,8 +92,8 @@ def serve_control(cell, seed: int, devices):
     """The program's widest gap and the fp8 control's, over the same
     sample of one window's served requests."""
     from bench import harness
-    from bench.reference import starcoder2 as ref
 
+    ref = cell.reference
     seqs = []
 
     def grab(objs):
@@ -114,7 +114,7 @@ def serve_control(cell, seed: int, devices):
         len(done), size=min(cell.traffic["check_requests"], len(done)),
         replace=False)
     out = {"program": {k: c["value"] for k, c in line["checks"].items()}}
-    for kind, dot in _controls().items():
+    for kind, dot in ref.CONTROLS.items():
         gaps = ref.served_gaps(ref.Sizes.of(cell.config),
                                harness.jax_seed(seed), done[pick], P, dot=dot)
         out[kind] = {"prompt_kept": 0.0, "logit_gap": float(gaps.max())}
@@ -138,7 +138,7 @@ def readings(cell, seed: int, devices):
         return serve_control(cell, seed, devices)
     line = harness.run_cell(cell, seed, 1.0, False, devices)
     out = {"program": {k: c["value"] for k, c in line["checks"].items()}}
-    out.update(train_readings(cell, seed))
+    out.update(train_readings(cell, seed, devices))
     return out
 
 

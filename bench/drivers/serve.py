@@ -34,7 +34,6 @@ from bench import harness, window
 from bench.drivers.train import model_diff
 from bench.gen.corpus import token_corpus
 from bench.gen.lake import build_store
-from bench.reference import starcoder2 as ref
 
 
 def run(ctx: harness.RunContext) -> harness.Record:
@@ -154,6 +153,7 @@ def run(ctx: harness.RunContext) -> harness.Record:
     gc.collect()
     window.log(f"live device bytes before the reference: "
                f"{sum(a.nbytes for a in jax.live_arrays())}")
+    ref = ctx.cell.reference
     gaps = ref.served_gaps(ref.Sizes.of(cfg), ctx.jax_seed, seqs[pick], P)
     limits = cfg["limits"]
     checks = {"prompt_kept": {"value": 0.0 if kept else 1.0, "limit": 0.0},

@@ -36,7 +36,6 @@ import numpy as np
 from bench import compare, harness, window
 from bench.gen.corpus import token_corpus
 from bench.gen.lake import build_store
-from bench.reference import starcoder2 as ref
 
 PROGRAM_KEYS = {"hidden_size": "d_model", "num_attention_heads": "num_heads",
                 "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
@@ -111,12 +110,18 @@ def run(ctx: harness.RunContext) -> harness.Record:
                    seed=ctx.jax_seed, checkpoint_every=1 << 40,
                    log_every=1 << 40)
     mesh = make_local_mesh(devices=ctx.devices)
+    if dict(mesh.shape) != cfg["mesh"]:
+        raise harness.BenchError(f"the cell's devices make the mesh "
+                                 f"{dict(mesh.shape)}; the config states "
+                                 f"{cfg['mesh']}")
     trainer = Trainer(job, data_ds=ds, mesh=mesh)
     check_config(trainer.cfg, trainer.opt, cfg)
     if ctx.patch:
         ctx.patch({"trainer": trainer})
     state, _ = trainer.initial_state(restore=False)
     batches = trainer._batches()
+    window.log(f"trainer and state: {time.perf_counter() - t0:.3f}s into "
+               f"set-up")
 
     # the first steps: the window's own call and feed, followed by the
     # reference.  Their batches, and every later one, are kept (on the
@@ -156,6 +161,8 @@ def run(ctx: harness.RunContext) -> harness.Record:
                     jax.tree_util.keystr(path, simple=True, separator="/"):
                     np.asarray(x) / np.float32(1 - opt["b1"])
                     for path, x in flat}
+        window.log(f"first steps and their gradient on the host: "
+                   f"{time.perf_counter() - t0:.3f}s into set-up")
         flat, _ = jax.tree_util.tree_flatten_with_path(state["params"])
         flat0 = jax.tree_util.tree_leaves(p0)
         change_prog = {
@@ -238,8 +245,12 @@ def run(ctx: harness.RunContext) -> harness.Record:
     window.log(f"live device bytes before the reference: "
                f"{sum(a.nbytes for a in jax.live_arrays())}")
     bad_blocks = compare.stream_errors(blocks, corpus, keep, epoch_starts)
+    ref = ctx.cell.reference
     reference = ref.train_steps(ref.Sizes.of(cfg), opt, ctx.jax_seed, first,
-                                against=grad_host)
+                                against=grad_host, devices=ctx.devices)
+    # the peak never falls: above the window's, it is the reference's
+    window.log(f"device memory peak after the reference "
+               f"{window.memory_peak(ctx.devices)} bytes (window's {mem})")
     del grad_host
     moved = compare.moved_leaves(reference["grad_norms"])
     limits = cfg["limits"]

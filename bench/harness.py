@@ -10,7 +10,27 @@ is a file of its own, found by name:
   driver ``bench/drivers/<kind>.py``;
 - ``bench/metrics/<metric>.py``: a per-layer metric, a ``read(rec)`` that
   returns a number or ``None`` when the run holds nothing to read;
-- ``bench/flops/<family>.py``: operations and bytes of a model family.
+- ``bench/flops/<family>.py``: operations and bytes of a model family;
+- ``bench/reference/<name>.py``: the plain float32 reference that decides
+  ``correct``, named by the configuration's ``reference`` key.  It imports
+  nothing of the program and keeps this contract:
+
+  - ``Sizes.of(cfg)``: the sizes it needs, from the configuration file; the
+    object is handed back as the first argument of the functions below;
+  - ``train_steps(sizes, opt, seed, batches, dot=..., against=None,
+    keep_grad=False, devices=None)`` (train cells): AdamW from the seed's
+    weights, one step per batch, returning ``losses``, ``grad_norms``
+    (per leaf, the first gradient as AdamW took it) and ``change_norms``
+    (per leaf, the weights' change after the last step); with
+    ``against`` (a first gradient per leaf on the host) also
+    ``grad_diff_norms``, with ``keep_grad`` also ``grad``.  Its weights,
+    gradient and moments live sharded over ``devices`` (default: the
+    default device alone);
+  - ``served_gaps(sizes, seed, seqs, prompt_len, dot=...)`` (serve cells):
+    at each served token of ``seqs`` (prompt and served tokens), how far
+    the reference's logit of the token lies below its best;
+  - ``CONTROLS``: the control's name to the product (``dot``) the two
+    functions take in place of their own (read by ``bench/control.py``).
 
 Adding a cell therefore needs new files and a new ``workloads`` entry only.
 """
@@ -43,6 +63,8 @@ class Cell:
     traffic_name: str
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
+    #: the configuration's reference module (``reference_for``)
+    reference: Any = None
 
 
 @dataclass
@@ -96,10 +118,11 @@ def load_json(path: Path) -> Any:
 def load_module(path: Path):
     """Import a harness file by path (metric names may hold '.' or '-')."""
     if not path.is_file():
-        raise BenchError(f"no such file: {path.relative_to(ROOT)}")
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace(".", "_").replace("-", "_"), path)
+        raise BenchError(f"no such file: {path}")
+    name = "bench_" + path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod        # dataclasses look their module up there
     spec.loader.exec_module(mod)
     return mod
 
@@ -119,6 +142,7 @@ def resolve_cell(spec: Dict[str, Any], name: str, root: Path = ROOT) -> Cell:
         raise BenchError(f"workload {name!r} names unknown config "
                          f"{w['config']!r}")
     config = load_json(root / configs[w["config"]]["file"])
+    reference = reference_for(config, root)
     traffic = load_json(root / "bench" / "traffic" / f"{w['traffic']}.json")
     e2e = [m for m in spec["end_to_end"] if _for_cell(m, name)]
     e2e_names = {m["name"] for m in e2e}
@@ -132,7 +156,7 @@ def resolve_cell(spec: Dict[str, Any], name: str, root: Path = ROOT) -> Cell:
                 f"workload {name!r} does not report")
     return Cell(name=name, chips=int(w["chips"]), config=config,
                 traffic=traffic, traffic_name=w["traffic"], end_to_end=e2e,
-                per_layer=per_layer)
+                per_layer=per_layer, reference=reference)
 
 
 def driver_for(cell: Cell, root: Path = ROOT):
@@ -144,6 +168,16 @@ def driver_for(cell: Cell, root: Path = ROOT):
 
 def flops_for(family: str, root: Path = ROOT):
     return load_module(root / "bench" / "flops" / f"{family}.py")
+
+
+def reference_for(config: Dict[str, Any], root: Path = ROOT):
+    """The reference module a configuration names; one that names none, or
+    one that is not there, is refused."""
+    name = config.get("reference")
+    if not isinstance(name, str) or not name.isidentifier():
+        raise BenchError(f"config of arch {config.get('arch')!r} names no "
+                         f"valid reference: {name!r}")
+    return load_module(root / "bench" / "reference" / f"{name}.py")
 
 
 def peaks(device_kind: str, root: Path = ROOT) -> Dict[str, float]:

@@ -8,7 +8,11 @@ are stored in the configuration's dtype (bfloat16) and every computation
 runs in float32, matrix products at ``Precision.HIGHEST``.  The loss, its
 gradient and the logits are computed layer by layer, with attention and the
 loss in blocks of queries, so that a whole step at the timed widths fits on
-one chip beside nothing else.
+one chip beside nothing else.  Handed several devices, ``train_steps``
+shards its weights, gradient and AdamW moments over them as FSDP does
+(``Layout``) and gathers each layer's weights where it is used; the
+mathematics is the same, and on one device the arrays are placed as they
+always were.
 
 Where this departs from the hf description of starcoder2
 (``bigcode/starcoder2-3b``), it follows the program's model instead, since
@@ -36,6 +40,7 @@ from typing import Any, Callable, Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 HI = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
@@ -70,6 +75,75 @@ class Sizes:
                    eps=float(cfg["norm_epsilon"]), dtype=cfg["dtype"])
 
 
+# ------------------------------------------------------------------ layout
+class Layout:
+    """Where the reference's arrays live.  On one device (or none named):
+    the default device, every call as it is.  On several: a mesh over
+    them, each weight, gradient and moment split along its first axis that
+    the device count divides (replicated where none does), as FSDP shards
+    them, each batch split by rows, and each layer's weights gathered whole
+    where the layer uses them."""
+
+    AXIS = "fsdp"
+
+    def __init__(self, devices: Sequence[Any] = None) -> None:
+        devices = list(devices or [])
+        self.mesh = (Mesh(np.asarray(devices), (self.AXIS,))
+                     if len(devices) > 1 else None)
+
+    def split(self, shape: Sequence[int]):
+        """The sharding of an array of ``shape`` split along its first
+        axis the device count divides, or None on one device."""
+        if self.mesh is None:
+            return None
+        n = self.mesh.size
+        for i, dim in enumerate(shape):
+            if dim % n == 0:
+                return NamedSharding(self.mesh, PartitionSpec(
+                    *([None] * i), self.AXIS))
+        return self.whole()
+
+    def whole(self):
+        return (None if self.mesh is None
+                else NamedSharding(self.mesh, PartitionSpec()))
+
+    def rows(self, shape: Sequence[int]):
+        """A batch's sharding: split by rows where they divide."""
+        if self.mesh is None:
+            return None
+        if shape[0] % self.mesh.size:
+            return self.whole()
+        return NamedSharding(self.mesh, PartitionSpec(self.AXIS))
+
+    def gather(self, x):
+        """``x`` whole on every device, inside a traced function."""
+        if self.mesh is None:
+            return x
+        return jax.lax.with_sharding_constraint(x, self.whole())
+
+    def by_rows(self, x):
+        """Activations split by rows, inside a traced function."""
+        if self.mesh is None:
+            return x
+        return jax.lax.with_sharding_constraint(x, self.rows(x.shape))
+
+    def put(self, x, sharding=None):
+        """A host array on the device(s): split by rows unless ``sharding``
+        is given."""
+        if self.mesh is None:
+            return jnp.asarray(x)
+        return jax.device_put(x, sharding or self.rows(np.shape(x)))
+
+    def jit(self, fn, out_shardings=None, **kw):
+        """``jax.jit``, with ``out_shardings`` on several devices only."""
+        if self.mesh is not None and out_shardings is not None:
+            kw["out_shardings"] = out_shardings
+        return jax.jit(fn, **kw)
+
+
+ONE = Layout()
+
+
 # ------------------------------------------------------------------ weights
 def leaf_specs(s: Sizes) -> List[Tuple[str, Tuple[int, ...], str, int]]:
     """(name, shape, init, fan_in) in the sorted-key order of the tree."""
@@ -92,8 +166,13 @@ def leaf_specs(s: Sizes) -> List[Tuple[str, Tuple[int, ...], str, int]]:
     ]
 
 
-def init_params(s: Sizes, seed: int) -> Dict[str, jax.Array]:
-    """The configuration's weights for ``seed``, built on the device."""
+def param_shardings(s: Sizes, lay: Layout) -> Dict[str, Any]:
+    return {name: lay.split(shape) for name, shape, _, _ in leaf_specs(s)}
+
+
+def init_params(s: Sizes, seed: int, lay: Layout = ONE
+                ) -> Dict[str, jax.Array]:
+    """The configuration's weights for ``seed``, built on the device(s)."""
     specs = leaf_specs(s)
 
     def build(key):
@@ -110,7 +189,7 @@ def init_params(s: Sizes, seed: int) -> Dict[str, jax.Array]:
                              ).astype(s.dtype)
         return out
 
-    return jax.jit(build)(jax.random.PRNGKey(seed))
+    return lay.jit(build, param_shardings(s, lay))(jax.random.PRNGKey(seed))
 
 
 def layer_leaves(params: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
@@ -137,6 +216,10 @@ def dot_fp8(x, w):
     range, with float32 accumulation: the precision below the
     configuration's bfloat16."""
     return dot_f32(_fp8(x.astype(F32)), _fp8(w.astype(F32)))
+
+
+#: the control: the precision below the configuration's bfloat16
+CONTROLS = {"fp8": dot_fp8}
 
 
 # ------------------------------------------------------------------ layers
@@ -199,17 +282,20 @@ def block(p, h, positions, s: Sizes, dot: Callable, q_block: int):
     return h + dot(u, p["mlp/wo"])
 
 
-def hidden(params, tokens, s: Sizes, dot: Callable, q_block: int = 512):
+def hidden(params, tokens, s: Sizes, dot: Callable, q_block: int = 512,
+           lay: Layout = ONE):
     """Final normed hidden states (B, S, d), float32."""
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    h = params["embed"].astype(F32)[tokens] * np.sqrt(s.d)
+    h = lay.by_rows(lay.gather(params["embed"]).astype(F32)[tokens]
+                    * np.sqrt(s.d))
 
     def body(h, p):
-        return block(p, h, positions, s, dot, q_block), None
+        return lay.by_rows(block(lay.gather(p), h, positions, s, dot,
+                                 q_block)), None
 
     h, _ = jax.lax.scan(jax.checkpoint(body), h, layer_leaves(params))
-    return rmsnorm(h, params["final_ln"], s.eps)
+    return rmsnorm(h, lay.gather(params["final_ln"]), s.eps)
 
 
 def logits_of(params, h, s: Sizes, dot: Callable):
@@ -217,16 +303,17 @@ def logits_of(params, h, s: Sizes, dot: Callable):
 
 
 def loss(params, batch, s: Sizes, dot: Callable = dot_f32,
-         chunk: int = 512):
+         chunk: int = 512, lay: Layout = ONE):
     """Token-mean cross entropy over ``loss_mask``, in blocks of positions."""
-    h = hidden(params, batch["tokens"], s, dot)
+    h = hidden(params, batch["tokens"], s, dot, lay=lay)
     B, S, d = h.shape
     c = min(chunk, S)
+    head = {"head": lay.gather(params["head"])}
 
     @jax.checkpoint
     def nll(args):
         hc, tc = args
-        lg = logits_of(params, hc, s, dot)
+        lg = logits_of(head, hc, s, dot)
         gold = jnp.take_along_axis(lg, tc[..., None], axis=-1)[..., 0]
         return jax.nn.logsumexp(lg, axis=-1) - gold
 
@@ -257,7 +344,8 @@ def clip_scale(opt: Dict[str, Any], g) -> jax.Array:
 def train_steps(s: Sizes, opt: Dict[str, Any], seed: int,
                 batches: Sequence[Dict[str, np.ndarray]],
                 dot: Callable = dot_f32, against=None,
-                keep_grad: bool = False) -> Dict[str, Any]:
+                keep_grad: bool = False,
+                devices: Sequence[Any] = None) -> Dict[str, Any]:
     """AdamW from the seed's weights over ``batches``, one step each.
 
     Returns the loss of every step, the per-leaf norm of the first step's
@@ -265,14 +353,21 @@ def train_steps(s: Sizes, opt: Dict[str, Any], seed: int,
     weights' change after the last step, as floats.  With ``against`` (a
     first gradient as AdamW took it, per leaf, on the host) also the
     per-leaf norm of its difference from this one; with ``keep_grad`` this
-    first gradient itself, on the host."""
-    params = init_params(s, seed)
+    first gradient itself, on the host.  Weights, gradient and moments live
+    sharded over ``devices`` (``Layout``)."""
+    lay = Layout(devices)
+    params = init_params(s, seed, lay)
     names = sorted(params)
-    vg = jax.jit(jax.value_and_grad(functools.partial(loss, s=s, dot=dot)))
+    shard = param_shardings(s, lay)
+    norms_sh = {k: lay.whole() for k in names}
+    vg = lay.jit(jax.value_and_grad(functools.partial(loss, s=s, dot=dot,
+                                                      lay=lay)),
+                 (lay.whole(), shard))
     scale_of = jax.jit(functools.partial(clip_scale, opt))
     diff = jax.jit(lambda a, g, sc: jnp.sqrt(jnp.sum(jnp.square(a - g * sc))))
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+    @functools.partial(lay.jit, out_shardings=(shard, shard, shard, norms_sh),
+                       donate_argnums=(0, 1, 2))
     def update(params, m, v, g, step):
         scale = clip_scale(opt, g)
         b1, b2, t = opt["b1"], opt["b2"], step.astype(F32)
@@ -292,19 +387,19 @@ def train_steps(s: Sizes, opt: Dict[str, Any], seed: int,
             new_p[k] = (p.astype(F32) + u.astype(F32)).astype(p.dtype)
         return new_p, new_m, new_v, gn
 
-    zeros = jax.jit(lambda p: {k: jnp.zeros(x.shape, F32)
-                               for k, x in p.items()})
+    zeros = lay.jit(lambda p: {k: jnp.zeros(x.shape, F32)
+                               for k, x in p.items()}, shard)
     m, v = zeros(params), zeros(params)
     out: Dict[str, Any] = {"losses": []}
     for i, b in enumerate(batches):
-        batch = {k: jnp.asarray(b[k]) for k in ("tokens", "targets",
-                                                 "loss_mask")}
+        batch = {k: lay.put(b[k]) for k in ("tokens", "targets",
+                                             "loss_mask")}
         lv, g = vg(params, batch)
         if i == 0:
             sc = scale_of(g)
             if against is not None:
                 out["grad_diff_norms"] = {
-                    k: float(diff(jnp.asarray(against[k]), g[k], sc))
+                    k: float(diff(lay.put(against[k], shard[k]), g[k], sc))
                     for k in names}
             if keep_grad:
                 out["grad"] = {k: np.asarray(g[k] * sc) for k in names}
@@ -316,7 +411,7 @@ def train_steps(s: Sizes, opt: Dict[str, Any], seed: int,
     # the start weights are built again rather than kept beside the
     # optimizer state, which leaves room for deeper cuts on one chip
     del m, v
-    p0 = init_params(s, seed)
+    p0 = init_params(s, seed, lay)
     change = jax.jit(lambda a, b: {k: jnp.sqrt(jnp.sum(jnp.square(
         a[k].astype(F32) - b[k].astype(F32)))) for k in names})(params, p0)
     out["change_norms"] = {k: float(x) for k, x in change.items()}

@@ -60,15 +60,15 @@ def _varint(n: int) -> bytes:
             return bytes(out)
 
 
-def keep_planes(raw: bytes) -> bytes:
-    """The serialized XSpace with only the planes of ``KEEP_PLANES``."""
+def keep_planes(raw: bytes, keep=KEEP_PLANES) -> bytes:
+    """The serialized XSpace with only the planes named in ``keep``."""
     out = bytearray()
     for field, plane in scopes.fields(memoryview(raw)):
         if field != 1:                  # errors, warnings, host names
             continue
         name = next((bytes(v).decode() for f, v in scopes.fields(plane)
                      if f == 2), "")
-        if name in KEEP_PLANES:
+        if name in keep:
             out += _varint(1 << 3 | 2) + _varint(len(plane)) + plane
     return bytes(out)
 

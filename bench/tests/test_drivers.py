@@ -4,6 +4,11 @@ have, planted in the timed path underneath, makes ``correct`` false."""
 
 from __future__ import annotations
 
+import copy
+import json
+import shutil
+from pathlib import Path
+
 import jax
 import pytest
 
@@ -11,6 +16,22 @@ from bench import harness
 from bench.tests import tiny
 
 SEED = 2 ** 33 + 5          # wider than 32 bits, as a run's seed may be
+HERE = Path(__file__).resolve().parent
+
+#: the numbers each rehearsal compared before the reference was found by
+#: name and could shard itself (the tree before that change, on the CPU):
+#: on one device they read exactly the same
+BEFORE = {
+    "lake": {"lake_bad_blocks": 0.0, "loss_gap": 1.6045348004343798e-07,
+             "grad_gap": 1.0052048591526574e-06,
+             "grad_err": 6.933329413352672e-07,
+             "change_gap": 3.941905173529145e-06},
+    "staged": {"lake_bad_blocks": 0.0, "loss_gap": 8.003773298884026e-08,
+               "grad_gap": 7.213413484258062e-07,
+               "grad_err": 7.27539519795663e-07,
+               "change_gap": 1.7591658789656695e-05},
+    "serve": {"prompt_kept": 0.0, "logit_gap": 0.0},
+}
 
 
 def run(name, patch=None, seconds=1.0, cell=None):
@@ -27,6 +48,7 @@ def test_a_sound_run_is_correct(name, capsys):
     assert line["device"]["platform"] == "cpu"
     for m in line["metrics"].values():
         assert m["value"] > 0
+    assert {k: c["value"] for k, c in line["checks"].items()} == BEFORE[name]
 
 
 def test_epochs_that_end_inside_the_window_are_checked_too():
@@ -90,3 +112,46 @@ def test_a_fault_underneath_is_not_correct(name, fault, check):
     assert not line["correct"]
     c = line["checks"][check]
     assert c["value"] > c["limit"], line["checks"]
+
+
+def test_a_cell_of_a_new_config_and_reference_needs_only_new_files(tmp_path):
+    """A configuration, its reference, a traffic mix and a per-layer metric,
+    each a new file, and new entries in ``BENCHMARK.json``: the cell
+    resolves, rehearses on the CPU through the new reference, and is
+    correct."""
+    root = tmp_path / "checkout"
+    shutil.copytree(harness.ROOT / "bench", root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    shutil.copy(HERE / "stub_reference.py",
+                root / "bench" / "reference" / "stub_model.py")
+    config = dict(copy.deepcopy(tiny.CONFIG), reference="stub_model")
+    (root / "bench/configs/stub-model.json").write_text(json.dumps(config))
+    (root / "bench/traffic/stub_staged.json").write_text(
+        json.dumps(tiny.TRAFFIC["staged"]))
+    (root / "bench/metrics/stub_steps.py").write_text(
+        "def read(rec):\n    return rec.layer.get('steps')\n")
+    spec = harness.load_json(harness.ROOT / "BENCHMARK.json")
+    spec["configs"].append({"name": "stub-model", "source": "a test",
+                            "file": "bench/configs/stub-model.json",
+                            "reduced": [], "why": "a test"})
+    spec["workloads"].append({"name": "stub-cell", "config": "stub-model",
+                              "traffic": "stub_staged", "chips": 1,
+                              "why": "a test"})
+    spec["per_layer"].append({"name": "stub_steps", "unit": "steps",
+                              "better": "higher", "source": "host_clock",
+                              "layer": "train step",
+                              "moves": "train_tokens_per_s",
+                              "workloads": ["stub-cell"]})
+    for m in spec["end_to_end"]:
+        if m["name"].startswith("train_"):
+            m["workloads"].append("stub-cell")
+    cell = harness.resolve_cell(spec, "stub-cell", root)
+    assert cell.reference.CALLS == []
+    ctx = harness.RunContext(cell=cell, seed=SEED, seconds=1.0, trace=False,
+                             devices=jax.devices()[:1])
+    rec = harness.driver_for(cell, root).run(ctx)
+    assert cell.reference.CALLS == ["train_steps"]
+    line = harness.result_line(cell, rec, False, {"platform": "cpu"}, root)
+    assert line["correct"], line["checks"]
+    got = harness.read_per_layer(cell, rec, root)
+    assert got["stub_steps"]["value"] == rec.layer["steps"] > 0

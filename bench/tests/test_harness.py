@@ -26,6 +26,10 @@ def test_every_cell_resolves_from_its_files(name):
     assert cell.config["arch"] and cell.traffic["kind"]
     assert harness.driver_for(cell).run
     assert harness.flops_for(cell.config["family"])
+    ref = cell.reference
+    assert callable(ref.Sizes.of) and ref.CONTROLS
+    assert callable(ref.train_steps if cell.traffic["kind"] == "train"
+                    else ref.served_gaps)
     for m in cell.per_layer:
         mod = harness.load_module(ROOT / "bench" / "metrics" / f"{m['name']}.py")
         assert callable(mod.read)
@@ -75,6 +79,23 @@ def test_a_new_cell_needs_only_new_files_and_an_entry(tmp_path):
     rec = harness.Record(metrics={}, attempted=1, failed=0,
                          memory_peak_bytes=0, checks={}, layer={"steps": 3})
     assert harness.read_per_layer(cell, rec, root)["steps_seen"]["value"] == 3
+
+
+@pytest.mark.parametrize("reference,error", [
+    (None, "names no valid reference"),
+    ("no_such_model", "no such file"),
+])
+def test_a_config_naming_no_reference_or_a_missing_one_is_refused(
+        tmp_path, reference, error):
+    root = _copy_tree(tmp_path)
+    path = root / "bench/configs/starcoder2-3b-l8-train.json"
+    cfg = harness.load_json(path)
+    cfg.pop("reference")
+    if reference is not None:
+        cfg["reference"] = reference
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(harness.BenchError, match=error):
+        harness.resolve_cell(SPEC, "sc2-train-lake-s3", root)
 
 
 def test_a_metric_whose_end_to_end_metric_the_cell_lacks_is_refused():

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import re
 from pathlib import Path
 
 import pytest
@@ -139,3 +140,104 @@ def test_a_trace_recorded_on_a_v5e():
     assert all(name.startswith("%") for name, _ in got.top_ops)
     assert sum(s for _, s in got.top_ops) == pytest.approx(got.busy_s,
                                                            rel=1e-3)
+
+
+def _hlo(name, opcode, shape="f32[8]{0}"):
+    return f"%{name} = {shape} {opcode}({shape} %x), metadata={{}}"
+
+
+def test_an_opcode_is_read_after_the_shape():
+    tuple_shape = ("(bf16[2048,2048]{1,0:T(8,128)(2,1)S(1)}, "
+                   "u32[]{:S(2)})")
+    assert trace.opcode(_hlo("copy-start", "copy-start", tuple_shape)) \
+        == "copy-start"
+    assert trace.opcode(_hlo("fusion.3", "fusion")) == "fusion"
+    assert trace.opcode("jit_step") == ""
+    # a collective by its opcode or its own name, not by its operands'
+    assert trace.is_collective(_hlo("ag.1", "all-gather-start"))
+    assert trace.is_collective(_hlo("all-reduce-fusion.2", "fusion"))
+    assert trace.is_collective(_hlo("async-collective-done", "fusion"))
+    assert not trace.is_collective(
+        "%copy.4 = f32[8]{0} copy(f32[8]{0} %all-gather-done.1)")
+
+
+COLL_TPU0 = {
+    "XLA Ops": [
+        (_hlo("while.1", "while", "(s32[])"), 0, 60),
+        (_hlo("all-gather-start.1", "all-gather-start"), 0, 5),
+        (_hlo("fusion.2", "fusion"), 5, 30),
+        (_hlo("all-gather-done.1", "all-gather-done"), 35, 5),
+        (_hlo("reduce-scatter.3", "reduce-scatter"), 40, 10),
+        (_hlo("all-reduce-fusion.1", "fusion"), 70, 10),
+        ("%copy.4 = f32[8]{0} copy(f32[8]{0} %all-gather-done.1)", 80, 10),
+        (_hlo("fusion.5", "fusion"), 90, 10),
+        (_hlo("collective-permute.5", "collective-permute"), 150, 10)],
+    "XLA Modules": [("jit_train_step(1)", 0, 60), ("jit_train_step(1)", 70, 20),
+                    ("jit_other(2)", 90, 10), ("jit_train_step(1)", 150, 10)]}
+COLL_TPU1 = {
+    "XLA Ops": [(_hlo("all-to-all.1", "all-to-all"), 10, 20),
+                (_hlo("fusion.9", "fusion"), 30, 20)],
+    "XLA Modules": [("jit_train_step(1)", 0, 50)]}
+
+
+def test_collectives_are_summed_per_chip_and_program():
+    """Chip 0: the step runs 0-60 and 70-90 ms; inside it the chip spends
+    5 + 5 + 10 + 10 ms in collectives (the loop that holds three of them
+    keeps only its own time, a copy of a gathered array is no collective,
+    and one after the window does not count).  Chip 1: 20 of 50 ms."""
+    got = trace.reduce(xspace({"/host:CPU": HOST, "/device:TPU:0": COLL_TPU0,
+                               "/device:TPU:1": COLL_TPU1}), [0, 1])
+    assert got.chips[0]["jit_train_step"] == (pytest.approx(0.08),
+                                              pytest.approx(0.03))
+    assert got.chips[0]["jit_other"] == (pytest.approx(0.01), 0.0)
+    assert got.chips[1] == {"jit_train_step": (pytest.approx(0.05),
+                                               pytest.approx(0.02))}
+    from bench import harness
+    read = harness.load_module(harness.ROOT / "bench" / "metrics"
+                               / "fsdp_exposed_collective_share.py").read
+    rec = harness.Record(metrics={}, attempted=1, failed=0,
+                         memory_peak_bytes=0, checks={},
+                         layer={"kind": "train"}, trace=got)
+    assert read(rec) == pytest.approx((0.03 / 0.08 + 0.02 / 0.05) / 2 * 100)
+    assert read(harness.Record(metrics={}, attempted=1, failed=0,
+                               memory_peak_bytes=0, checks={},
+                               layer={"kind": "serve"}, trace=got)) is None
+
+
+def test_one_chip_has_no_collectives():
+    got = trace.reduce(ProfileData.from_file(
+        str(DATA / "v5e_small.xplane.pb")), device_ids=[0])
+    assert got.chips[0]["jit__lambda"][0] > 0
+    assert all(c == 0.0 for _, c in got.chips[0].values())
+
+
+def test_the_collectives_of_a_four_chip_v5e_trace_by_hand():
+    """The FSDP step of ``record_fsdp.py`` on a v5e-4: per chip, the
+    durations of the collective operations on the ops line (none holds
+    another) over those of the step's calls, every one of them inside the
+    window, averaged over the chips."""
+    from bench import harness
+    profile = ProfileData.from_file(str(DATA / "v5e4_fsdp.xplane.pb"))
+    shares, kinds = [], set()
+    for i in range(4):
+        (plane,) = [p for p in profile.planes
+                    if p.name == f"/device:TPU:{i}"]
+        lines = {ln.name: ln for ln in plane.lines}
+        step = sum(e.duration_ns for e in lines["XLA Modules"].events
+                   if e.name.startswith("jit_train_step"))
+        coll = [e for e in lines["XLA Ops"].events
+                if re.match(r"%(all-gather|all-reduce|reduce-scatter"
+                            r"|collective-permute|all-to-all"
+                            r"|async-collective)", e.name)]
+        kinds |= {trace.opcode(e.name) for e in coll}
+        shares.append(sum(e.duration_ns for e in coll) / step)
+    got = trace.reduce(profile, [0, 1, 2, 3])
+    read = harness.load_module(harness.ROOT / "bench" / "metrics"
+                               / "fsdp_exposed_collective_share.py").read
+    share = read(harness.Record(metrics={}, attempted=1, failed=0,
+                                memory_peak_bytes=0, checks={},
+                                layer={"kind": "train"}, trace=got))
+    assert share == pytest.approx(sum(shares) / 4 * 100, rel=1e-9)
+    assert 0 < share < 100
+    # synchronous gathers and reductions, and an asynchronous gather
+    assert {"all-gather", "all-reduce", "fusion"} <= kinds

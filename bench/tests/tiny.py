@@ -9,12 +9,13 @@ from typing import Any, Dict
 from bench import harness
 
 CONFIG: Dict[str, Any] = {
-    "arch": "starcoder2-3b", "family": "dense", "smoke": True,
+    "arch": "starcoder2-3b", "family": "dense", "reference": "starcoder2",
+    "smoke": True,
     "hidden_size": 128, "num_attention_heads": 4, "num_key_value_heads": 1,
     "head_dim": 32, "intermediate_size": 256, "vocab_size": 512,
     "vocab_pad_multiple": 256, "sliding_window": 64,
     "num_hidden_layers": 4, "rope_theta": 999999.0, "norm_epsilon": 1e-06,
-    "dtype": "float32", "chips": 1,
+    "dtype": "float32", "chips": 1, "mesh": {"data": 1, "model": 1},
     "train": {"global_batch": 2, "seq_len": 64, "remat": "none"},
     "optimizer": {"name": "adamw", "lr": 1e-3, "warmup": 1,
                   "schedule_steps": 1000, "lr_floor": 0.1, "b1": 0.9,
@@ -56,10 +57,15 @@ E2E = {"train": ["train_tokens_per_s", "train_step_ms_p90", "setup_s"],
        "serve": ["serve_tokens_per_s", "setup_s"]}
 
 
+#: the tiny model trained FSDP over four devices, a row of the batch each
+FSDP4 = dict(CONFIG, chips=4, mesh={"data": 4, "model": 1},
+             train=dict(CONFIG["train"], global_batch=4))
+
+
 def cell(traffic: str, config: Dict[str, Any] = CONFIG) -> harness.Cell:
     t = copy.deepcopy(TRAFFIC[traffic])
     return harness.Cell(
-        name=f"tiny-{traffic}", chips=1, config=copy.deepcopy(config),
-        traffic=t, traffic_name=traffic,
+        name=f"tiny-{traffic}", chips=config["chips"],
+        config=copy.deepcopy(config), traffic=t, traffic_name=traffic,
         end_to_end=[{"name": n, "unit": "u"} for n in E2E[t["kind"]]],
-        per_layer=[])
+        per_layer=[], reference=harness.reference_for(config))

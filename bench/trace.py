@@ -13,7 +13,19 @@ numbers.
 - idle gaps: the stretches of the window in which the first chip ran
   nothing, each labelled by the innermost ``bench.*`` host span open at
   its middle (``bench.*`` spans are ``TraceAnnotation``s, on the trace's
-  own clock), summed per label.
+  own clock), summed per label;
+- per chip and per jitted program, its device seconds and the self time
+  of the collective operations inside it (all-gather, reduce-scatter,
+  all-reduce, all-to-all, collective-permute, by the operation's name or
+  opcode: their ``-start``/``-done`` halves and fusions named after them,
+  and the ``async-collective-start``/``-done`` fusions in which a v5e
+  starts and ends an asynchronous all-gather).  An asynchronous
+  collective shows on the ops line only where the chip waits for it: its
+  start and done.  What overlaps them is other operations there (on a
+  v5e, ``%fusion.N`` calling an ``%async_collective_fusion``: compute
+  scheduled inside the gather), and the transfer itself is on the
+  ``Async XLA Ops`` line, which is not read.  So this is the collectives'
+  exposed time, and it lies within the program's.
 
 The device planes run on a clock of their own.  Each program's start on the
 device is matched with its ``DoEnqueueProgram`` on the host by ``run_id``,
@@ -36,6 +48,9 @@ DEVICE_PREFIX = "/device:TPU:"
 HOST_SPAN_PREFIX = "bench."
 WINDOW_SPAN = "bench.window"
 _SUFFIX = re.compile(r"\(\d+\)$")
+_OPCODE = re.compile(r"\s([a-z][a-z0-9-]*)\(")
+COLLECTIVE = re.compile(r"all-gather|reduce-scatter|all-reduce|all-to-all"
+                        r"|collective-permute|async-collective")
 
 Interval = Tuple[float, float]
 
@@ -47,6 +62,8 @@ class TraceSummary:
     modules: Dict[str, Tuple[float, float]] = field(default_factory=dict)
     top_ops: List[Tuple[str, float]] = field(default_factory=list)
     idle_gaps: List[Tuple[str, float]] = field(default_factory=list)
+    #: per chip used, per program: (device seconds, collective self seconds)
+    chips: List[Dict[str, Tuple[float, float]]] = field(default_factory=list)
 
     @property
     def idle_share(self) -> float:
@@ -96,15 +113,28 @@ def short_name(hlo: str) -> str:
     return hlo.split(" = ", 1)[0]
 
 
-def self_times(evs: Sequence[Tuple[str, float, float]]
-               ) -> Dict[str, float]:
-    """Seconds per name not covered by an event nested inside it."""
-    out: Dict[str, float] = {}
+def opcode(hlo: str) -> str:
+    """``%fusion.3 = bf16[...] fusion(...)`` -> ``fusion`` ('' if none)."""
+    parts = hlo.split(" = ", 1)
+    m = _OPCODE.search(" " + parts[1]) if len(parts) == 2 else None
+    return m.group(1) if m else ""
+
+
+def is_collective(hlo: str) -> bool:
+    return bool(COLLECTIVE.search(short_name(hlo))
+                or COLLECTIVE.search(opcode(hlo)))
+
+
+def own_times(evs: Sequence[Tuple[str, float, float]]
+              ) -> List[Tuple[str, float, float, float]]:
+    """(name, start, end, seconds not covered by an event nested inside
+    it) of every event, in the order they close."""
+    out: List[Tuple[str, float, float, float]] = []
     stack: List[List] = []          # [name, start, end, child seconds]
 
     def close(item):
         name, a, b, kids = item
-        out[name] = out.get(name, 0.0) + (b - a) - kids
+        out.append((name, a, b, (b - a) - kids))
         if stack:
             stack[-1][3] += b - a
 
@@ -114,6 +144,36 @@ def self_times(evs: Sequence[Tuple[str, float, float]]
         stack.append([name, a, b, 0.0])
     while stack:
         close(stack.pop())
+    return out
+
+
+def self_times(evs: Sequence[Tuple[str, float, float]]
+               ) -> Dict[str, float]:
+    """Seconds per name not covered by an event nested inside it."""
+    out: Dict[str, float] = {}
+    for name, _, _, own in own_times(evs):
+        out[name] = out.get(name, 0.0) + own
+    return out
+
+
+def collectives_by_program(owned: Sequence[Tuple[str, float, float, float]],
+                          programs: Sequence[Tuple[str, float, float]]
+                          ) -> Dict[str, float]:
+    """Self seconds of the collective operations among ``owned`` (as
+    ``own_times`` gives them, full HLO names), per program of ``programs``
+    ((name, start, end) on the same clock) that holds the operation's
+    middle, each clipped to the program's interval."""
+    progs = sorted(programs, key=lambda p: p[1])
+    starts = [a for _, a, _ in progs]
+    out: Dict[str, float] = {}
+    for name, a, b, own in owned:
+        if not is_collective(name):
+            continue
+        i = bisect.bisect_right(starts, (a + b) / 2) - 1
+        if i < 0 or progs[i][2] < (a + b) / 2:
+            continue
+        key, pa, pb = progs[i]
+        out[key] = out.get(key, 0.0) + min(own, min(b, pb) - max(a, pa))
     return out
 
 
@@ -199,6 +259,7 @@ def reduce(profile, device_ids: Optional[Sequence[int]] = None
     planes = _device_planes(profile, device_ids)
     n = len(planes)
     busy_total, modules, ops = 0.0, {}, {}
+    chips: List[Dict[str, Tuple[float, float]]] = []
     first_busy: List[Interval] = []
     for i, plane in enumerate(planes):
         lines = {ln.name: ln for ln in plane.lines}
@@ -206,7 +267,7 @@ def reduce(profile, device_ids: Optional[Sequence[int]] = None
             raise ValueError(f"{plane.name} has no {OPS_LINE!r} line; lines: "
                              f"{sorted(lines)}")
         shift = clock_shift(profile, lines.get(MODULES_LINE))
-        evs = [(short_name(nm), a + shift, b + shift)
+        evs = [(nm, a + shift, b + shift)
                for nm, a, b in _events(lines[OPS_LINE])]
         evs = [(nm, max(a, lo), min(b, hi)) for nm, a, b in evs
                if b > lo and a < hi]
@@ -214,16 +275,26 @@ def reduce(profile, device_ids: Optional[Sequence[int]] = None
         busy_total += sum(b - a for a, b in busy)
         if i == 0:
             first_busy = busy
-        for nm, sec in self_times(evs).items():
+        owned = own_times(evs)
+        for nm, sec in self_times([(short_name(nm), a, b)
+                                   for nm, a, b in evs]).items():
             ops[nm] = ops.get(nm, 0.0) + sec / n
+        progs = []
         for nm, a, b in (_events(lines[MODULES_LINE])
                          if MODULES_LINE in lines else []):
             a, b = a + shift, b + shift
             if b > lo and a < hi:
                 key = _SUFFIX.sub("", nm)
+                progs.append((key, max(a, lo), min(b, hi)))
                 sec, calls = modules.get(key, (0.0, 0.0))
                 modules[key] = (sec + (min(b, hi) - max(a, lo)) / n,
                                 calls + 1.0 / n)
+        coll = collectives_by_program(owned, progs)
+        chip: Dict[str, Tuple[float, float]] = {}
+        for key, a, b in progs:
+            sec, _ = chip.get(key, (0.0, 0.0))
+            chip[key] = (sec + (b - a), coll.get(key, 0.0))
+        chips.append(chip)
     idle: Dict[str, float] = {}
     label = _labeller(spans)
     for a, b in gaps(first_busy, lo, hi):
@@ -232,7 +303,7 @@ def reduce(profile, device_ids: Optional[Sequence[int]] = None
     return TraceSummary(
         busy_s=busy_total / n, window_s=hi - lo, modules=modules,
         top_ops=sorted(ops.items(), key=lambda kv: -kv[1]),
-        idle_gaps=sorted(idle.items(), key=lambda kv: -kv[1]))
+        idle_gaps=sorted(idle.items(), key=lambda kv: -kv[1]), chips=chips)
 
 
 def reduce_dir(log_dir: str, device_ids: Optional[Sequence[int]] = None
